@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.data import tokenizer as tok
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.serve import Engine, ServeConfig
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch).smoke()
     print(f"serving {args.arch} (smoke config, "

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import coding, layer, network
+from repro.launch import compile_cache
 from repro.serve import tnn_engine
 
 
@@ -57,6 +58,7 @@ def main():
                     choices=["auto", "scan", "closed_form", "event",
                              "pallas"])
     args = ap.parse_args()
+    compile_cache.enable()
 
     net = build_network()
     params = network.init_network(jax.random.PRNGKey(0), net)

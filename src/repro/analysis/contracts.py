@@ -9,8 +9,7 @@ variant every call would pass. These guards watch the real signal
   * :func:`assert_max_compiles` — context manager counting XLA backend
     compiles inside the block via ``jax.monitoring``'s
     ``/jax/core/compile/backend_compile_duration`` events (one per
-    backend compile, zero on cache hits — verified against the pinned
-    jax 0.4.37 and the latest CI leg). Because eager jnp ops also
+    backend compile, zero on cache hits). Because eager jnp ops also
     compile on first touch, steady-state contracts should warm up
     OUTSIDE the guard and then assert ``assert_max_compiles(0)``.
   * :func:`assert_no_tracer_leaks` — a gc-walk canary for jax tracers

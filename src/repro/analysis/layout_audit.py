@@ -115,7 +115,7 @@ def audit_scope(mesh, report: AuditReport,
     def checked_wsc(x, *spec):
         y = orig(x, *spec)
         am = compat.get_abstract_mesh()
-        if am is None or not am.axis_names:
+        if not am.axis_names:
             return y
         expected = P(*(sharding_specs.ambient_fit(d, e)
                        for d, e in zip(x.shape, spec)))

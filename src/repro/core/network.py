@@ -271,18 +271,11 @@ def _forward_pipelined(params, x, cfg, carry_in, m, rows) -> ForwardResult:
 
     Bit-exact vs the barriered schedule for every backend and any M: a
     ragged ``B % M != 0`` batch is NO_SPIKE-padded to full micro-batches
-    (padding rows carry silent state). Under an active mesh the tick scan
-    is fully unrolled (the tick count M + L - 1 is static): XLA's
-    while-loop carry layout propagation miscompiles a cross-layer stage
-    carry on a data-sharded mesh (jax 0.4.x — wrong *values*, not just
-    layouts), and straight-line code sidesteps the loop entirely.
+    (padding rows carry silent state).
     """
     b = x.shape[0]
     n_layers = len(cfg.layers)
     if m * rows > b:             # ragged tail: NO_SPIKE rows are inert
-        # jnp.pad, not a concat with a replicated block: concatenating a
-        # fresh all-NO_SPIKE array onto the data-sharded batch trips the
-        # same jax 0.4.x SPMD miscompile the unroll below dodges
         x = jnp.pad(x, ((0, m * rows - b), (0, 0)),
                     constant_values=int(coding.NO_SPIKE))
     xs = x.reshape(m, rows, x.shape[-1])
@@ -323,10 +316,8 @@ def _forward_pipelined(params, x, cfg, carry_in, m, rows) -> ForwardResult:
                                                           *stage_axes))
         return tuple(new_stage), (out, tuple(wins), tuple(couts))
 
-    ticks = m + n_layers - 1
-    unroll = ticks if neuron.mesh_active() else 1
     _, (ys_out, ys_win, ys_carry) = jax.lax.scan(
-        tick, stage0, (xs, tuple(carry_xs)), unroll=unroll)
+        tick, stage0, (xs, tuple(carry_xs)))
     # layer l's tick-t output belongs to micro-batch t - l: the last
     # layer's valid outputs are ticks L-1 .. L-1+M-1, layer l's winners
     # (and carry blocks) ticks l .. l+M-1; outside is warmup/drain pad.

@@ -278,9 +278,8 @@ def clip_k(cfg: NeuronConfig) -> Optional[int]:
     return cfg.k if cfg.dendrite in ("sorting_pc", "catwalk") else None
 
 
-# capability probes: canonical in repro.core.policy, re-exported verbatim
-# (not deprecated — they are ambient-environment facts, not policy)
-pallas_available = engine_policy.pallas_available
+# capability probe: canonical in repro.core.policy, re-exported verbatim
+# (not deprecated — an ambient-environment fact, not policy)
 mesh_active = engine_policy.mesh_active
 
 ColumnCounts = engine_policy.ColumnCounts
@@ -376,9 +375,9 @@ def fire_times_bank(times: jax.Array, weights: jax.Array, cfg: NeuronConfig,
         bank, or per column stack for 3-D inputs; tick loop early-exits at
         the batch's last breakpoint. Under an active mesh, shardable
         column stacks run one launch per column tile via the shard_map
-        wrappers (:mod:`repro.kernels.rnl_shard`, see
-        :func:`pallas_shardable`); non-shardable shapes degrade to the
-        jnp engines (:func:`effective_engine`).
+        wrappers (:mod:`repro.kernels.rnl_shard`); an explicit request
+        for a shape they cannot tile raises (only ``"auto"`` falls back
+        to the jnp engines).
       * ``"pallas_compact"`` — the same fused sweep over spike-compacted
         volleys (:func:`repro.kernels.rnl_neuron.rnl_fire_times_compact`):
         active lines relocated to a dense prefix of width ``n_active_max``
@@ -431,9 +430,10 @@ def fire_times_bank(times: jax.Array, weights: jax.Array, cfg: NeuronConfig,
     if pol.wants_density(backend, n_columns):
         density, s_active = compaction.active_stats(times, cfg.t_steps)
     # Pallas under an active mesh: shardable column stacks run through the
-    # shard_map wrappers below; everything else (2-D banks, non-dividing
-    # C — the replication fallback) degrades to the bit-exact jnp engine
-    # of the same sparsity class (DESIGN.md §6.4).
+    # shard_map wrappers below; for everything else (2-D banks,
+    # non-dividing C) "auto" degrades to the bit-exact jnp engine of the
+    # same sparsity class and an explicit Pallas request raises in
+    # resolve() (DESIGN.md §6.4).
     shape = engine_policy.BankShape(
         pairs=(n_columns or 1) * times.shape[-2] * weights.shape[-2],
         n_lines=times.shape[-1], t_steps=cfg.t_steps)
@@ -441,14 +441,12 @@ def fire_times_bank(times: jax.Array, weights: jax.Array, cfg: NeuronConfig,
                          column_counts=n_columns, shape=shape).engine
 
     if engine in ("pallas", "pallas_compact"):
-        # an explicit pallas request must not silently degrade — only
-        # "auto" falls back (the policy already guards availability)
         from repro.kernels import rnl_neuron
         if times.ndim not in (2, 3):
             raise ValueError(f"{engine} backend supports (B, n) or "
                              f"(C, B, n) volleys, got {times.shape}")
-        # effective_engine only lets a Pallas engine through under a mesh
-        # when the column stack clears pallas_shardable
+        # resolve() only lets a Pallas engine through under a mesh when
+        # the column stack tiles the column axis
         sharded = mesh_active() and times.ndim == 3
         if engine == "pallas_compact":
             comp, w_c = _compact_bank(times, weights, cfg.t_steps,

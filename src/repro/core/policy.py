@@ -32,11 +32,12 @@ re-derives the fit from an artifact's result rows so the property suite
 can assert the committed defaults and a fresh fit pick the same engine on
 every committed cell (tests/test_policy.py).
 
-Resolution semantics are unchanged where they were already right:
-explicit backend names pass through, the fused Pallas kernel preempts on
-TPU, Pallas engines degrade to their bit-exact jnp class under a mesh the
-column stack cannot tile, and an unknown workload (tracing: no density,
-no shape) keeps the dense choice. The cost model replaces only the
+Resolution semantics: an explicit backend name runs as named or raises
+(a Pallas engine under a mesh its column stack cannot tile is an error,
+never a silent switch); the fused Pallas kernel preempts ``auto`` on TPU;
+only ``auto`` degrades a Pallas pick to its bit-exact jnp class under such
+a mesh; and an unknown workload (tracing: no density, no shape) keeps the
+dense choice. The cost model replaces only the
 event-vs-closed-form boundary — and, it turns out, moves it: on the
 committed sweep the event engine still wins at density 0.5 (59 ms vs 72
 ms), which the 0.25 threshold got wrong.
@@ -76,20 +77,6 @@ SPARSE_ENGINES = ("event", "pallas_compact")
 ColumnCounts = Union[int, Iterable[int], None]
 
 
-def pallas_available() -> bool:
-    """Whether the fused Pallas neuron-bank kernel can run here.
-
-    True on a TPU backend (Mosaic lowering) and on CPU via the Pallas
-    interpreter (bit-accurate, slow — fine for tests, wrong choice for
-    training loops, hence the ``auto`` policy below).
-    """
-    try:
-        from repro.kernels import rnl_neuron  # noqa: F401
-        return True
-    except Exception:  # pragma: no cover - pallas/toolchain missing
-        return False
-
-
 def mesh_active() -> bool:
     """Whether an ambient device mesh is entered (compat.set_mesh).
 
@@ -100,8 +87,7 @@ def mesh_active() -> bool:
     jnp engines, which are sharding-transparent and keep the layout the
     layer constraints pin (DESIGN.md §6.4).
     """
-    am = compat.get_abstract_mesh()
-    return am is not None and bool(am.axis_names)
+    return bool(compat.get_abstract_mesh().axis_names)
 
 
 def _pallas_shardable(n_columns: Optional[int]) -> bool:
@@ -116,28 +102,29 @@ def _pallas_shardable(n_columns: Optional[int]) -> bool:
       * mesh without a ``column`` axis -> False (nothing to map over);
       * otherwise ``n_columns %% column-axis-size == 0``.
 
-    When this returns False the engines degrade exactly as the pre-shard
-    replication fallback did (:func:`_effective_engine`).
+    When this returns False, ``auto`` degrades to the jnp engines
+    (:func:`_effective_engine`) and an explicit Pallas request raises.
     """
     if not mesh_active():
         return True
     if n_columns is None:
         return False
-    am = compat.get_abstract_mesh()
-    if sharding_specs.TNN_COLUMN_AXIS not in (am.axis_names or ()):
+    if sharding_specs.TNN_COLUMN_AXIS not in \
+            compat.get_abstract_mesh().axis_names:
         return False
     return n_columns % sharding_specs.tnn_column_size() == 0
 
 
 def _effective_engine(engine: str,
                       column_counts: ColumnCounts = None) -> str:
-    """The engine that will actually run for ``engine`` given the ambient
+    """The engine that can actually run for ``engine`` given the ambient
     mesh. The Pallas engines pass through when every column count in
     ``column_counts`` is :func:`_pallas_shardable` (the shard_map fast
     path serves them); otherwise — replication fallback, a 2-D bank, or an
-    unknown shape (``column_counts=None``) — they degrade to the bit-exact
-    jnp engine of the same sparsity class, exactly the pre-shard behavior.
-    Everything else passes through unconditionally.
+    unknown shape (``column_counts=None``) — the answer is the bit-exact
+    jnp engine of the same sparsity class. Everything else passes through
+    unconditionally. :meth:`EnginePolicy.resolve` takes that answer only
+    for ``auto``; an explicit Pallas request it cannot serve raises.
 
     ``column_counts`` is one count (a single bank call), an iterable of
     per-layer counts (the serve engine resolving for a whole network), or
@@ -169,7 +156,8 @@ class BankShape(NamedTuple):
 class Resolution(NamedTuple):
     """What :meth:`EnginePolicy.resolve` decided, and why.
 
-    engine:       the engine that will run (post mesh degradation).
+    engine:       the engine that will run (post mesh degradation, which
+                  only ``auto`` undergoes).
     requested:    the pre-degradation pick — the explicit backend name, or
                   the policy's cost/threshold choice for ``auto``.
     width:        compaction bucket width for the sparse engines (None
@@ -290,9 +278,10 @@ class EnginePolicy:
     ``mode="density"`` reproduces the legacy ``DENSITY_EVENT_MAX``
     threshold exactly (the escape hatch, and what the deprecated
     ``resolve_backend`` wrapper delegates to). Both modes keep the
-    non-negotiable parts of resolution: explicit names pass through, TPU
-    preempts with the fused Pallas kernel, mesh degradation applies last,
-    and an unknown workload stays dense.
+    non-negotiable parts of resolution: explicit names run as named or
+    raise, TPU preempts ``auto`` with the fused Pallas kernel, mesh
+    degradation applies last and to ``auto`` only, and an unknown workload
+    stays dense.
 
     Frozen (hashable) so a policy can ride on the frozen layer configs and
     key jit-variant caches; construction is cheap, but prefer the memoized
@@ -332,6 +321,11 @@ class EnginePolicy:
         capability check) and the bank shape (for the predictor), and
         returns the :class:`Resolution` — engine, pre-degradation request,
         compaction width, and the predictions behind the choice.
+
+        Raises ValueError when an explicit Pallas backend cannot run under
+        the ambient mesh (a column count that does not tile its
+        ``column`` axis, a 2-D bank, or an unknown shape): only ``auto``
+        may fall back to a jnp engine.
         """
         predicted: Dict[str, float] = {}
         s_active = self._active_lines(density, max_active, shape)
@@ -356,6 +350,14 @@ class EnginePolicy:
             # dict order breaks exact ties toward the sparse engine
             requested = min(predicted, key=predicted.__getitem__)
         engine = _effective_engine(requested, column_counts)
+        if engine != requested and backend != "auto":
+            raise ValueError(
+                f"backend={backend!r} cannot run under the active mesh for "
+                f"column counts {column_counts!r} (the shard_map kernels "
+                f"need 3-D column stacks that tile the "
+                f"{sharding_specs.TNN_COLUMN_AXIS!r} axis, size "
+                f"{sharding_specs.tnn_column_size()}); use backend='auto' "
+                f"to allow the jnp fallback")
         width = (self.width_for(s_active, shape)
                  if engine in SPARSE_ENGINES and s_active is not None
                  else None)
@@ -386,7 +388,7 @@ class EnginePolicy:
     def _pallas_preempts(self, column_counts: ColumnCounts) -> bool:
         """TPU fast path: the fused kernel preempts measurement-driven
         selection whenever it can actually run (DESIGN.md §3.3)."""
-        return (jax.default_backend() == "tpu" and pallas_available()
+        return (jax.default_backend() == "tpu"
                 and _effective_engine("pallas", column_counts) == "pallas")
 
     def _active_lines(self, density: Optional[float],

@@ -1,8 +1,8 @@
 """Shared kernel plumbing: interpret-mode selection and tiling helpers.
 
-TPU v5e is the TARGET; this container is CPU-only, so kernels default to
-``interpret=True`` (the Pallas interpreter executes the kernel body in
-Python for bit-accurate validation). On a real TPU backend the same
+TPU v5e is the target. On a CPU backend (the test suite) kernels default
+to ``interpret=True``: the Pallas interpreter executes the kernel body in
+Python for bit-accurate validation. On a TPU backend the same
 ``pl.pallas_call`` lowers to Mosaic.
 """
 

@@ -32,13 +32,14 @@ paper's sparsity-violation diagnostic), fused into the same tick sweep at
 no extra HBM read. Early exit cannot change clip counts: past the last
 breakpoint every popcount is zero.
 
-Block shapes (bank):
+Block shapes (bank; ``q_tile`` from :func:`_q_tiling` — the whole padded Q
+up to 128 neurons, else 128):
   t_hi    (1,)             int32 SMEM (shared by all tiles)
   times   (B_TILE, n)      int32
-  weights (Q_TILE, n)      int32
-  fire    (B_TILE, Q_TILE) int32 out   [+ clip (B_TILE, Q_TILE) int32 out]
+  weights (q_tile, n)      int32
+  fire    (B_TILE, q_tile) int32 out   [+ clip (B_TILE, q_tile) int32 out]
 
-Block shapes (compact): times (B_TILE, s); weights (B_TILE, Q_TILE, s) —
+Block shapes (compact): times (B_TILE, s); weights (B_TILE, q_tile, s) —
 per-volley after the compaction gather.
 """
 
@@ -58,7 +59,21 @@ from repro.core.coding import NO_SPIKE
 NO_SPIKE_INT = int(NO_SPIKE)
 
 B_TILE = 8
-Q_TILE = 8
+
+
+def _q_tiling(qsz: int) -> tuple[int, int]:
+    """(padded Q, neuron tile) for the ``(B_TILE, Q)`` output block.
+
+    Mosaic needs the block's last dim to be a multiple of the 128-lane
+    width or the whole array dim: a bank of up to 128 neurons is one tile
+    spanning the whole padded Q (padded to the sublane quantum, since Q is
+    also the second-to-last dim of the weight block); wider banks pad to
+    whole lanes and tile at 128.
+    """
+    q_pad = common.round_up(qsz, common.SUBLANE)
+    if q_pad <= common.LANE:
+        return q_pad, q_pad
+    return common.round_up(qsz, common.LANE), common.LANE
 
 
 def _tick_sweep(times, w, *, t_hi, threshold, k):
@@ -176,7 +191,7 @@ def rnl_fire_times(times: jax.Array, weights: jax.Array, *, t_steps: int,
     qsz, n2 = weights.shape
     assert n == n2, (times.shape, weights.shape)
     b_pad = common.round_up(bsz, B_TILE)
-    q_pad = common.round_up(qsz, Q_TILE)
+    q_pad, q_tile = _q_tiling(qsz)
     # pad silent lines / zero-weight neurons: they never fire, harmless
     times_p = jnp.pad(times, ((0, b_pad - bsz), (0, 0)),
                       constant_values=int(NO_SPIKE))
@@ -188,13 +203,13 @@ def rnl_fire_times(times: jax.Array, weights: jax.Array, *, t_steps: int,
         jnp.where(times_p < t_steps, times_p + w_line[None, :], 0), t_steps,
         threshold)
 
-    grid = (b_pad // B_TILE, q_pad // Q_TILE)
+    grid = (b_pad // B_TILE, q_pad // q_tile)
     in_specs = [
         _smem_scalar_spec(),
         pl.BlockSpec((B_TILE, n), lambda b, q: (b, 0)),
-        pl.BlockSpec((Q_TILE, n), lambda b, q: (q, 0)),
+        pl.BlockSpec((q_tile, n), lambda b, q: (q, 0)),
     ]
-    out_spec = pl.BlockSpec((B_TILE, Q_TILE), lambda b, q: (b, q))
+    out_spec = pl.BlockSpec((B_TILE, q_tile), lambda b, q: (b, q))
     if not with_clip:
         out = pl.pallas_call(
             functools.partial(_rnl_kernel, threshold=threshold, k=k),
@@ -237,7 +252,7 @@ def rnl_fire_times_layer(times: jax.Array, weights: jax.Array, *,
     c2, qsz, n2 = weights.shape
     assert csz == c2 and n == n2, (times.shape, weights.shape)
     b_pad = common.round_up(bsz, B_TILE)
-    q_pad = common.round_up(qsz, Q_TILE)
+    q_pad, q_tile = _q_tiling(qsz)
     times_p = jnp.pad(times, ((0, 0), (0, b_pad - bsz), (0, 0)),
                       constant_values=int(NO_SPIKE))
     weights_p = jnp.pad(weights, ((0, 0), (0, q_pad - qsz), (0, 0)))
@@ -246,13 +261,13 @@ def rnl_fire_times_layer(times: jax.Array, weights: jax.Array, *,
         jnp.where(times_p < t_steps, times_p + w_line[:, None, :], 0),
         t_steps, threshold)
 
-    grid = (csz, b_pad // B_TILE, q_pad // Q_TILE)
+    grid = (csz, b_pad // B_TILE, q_pad // q_tile)
     in_specs = [
         _smem_scalar_spec(),
         pl.BlockSpec((1, B_TILE, n), lambda c, b, q: (c, b, 0)),
-        pl.BlockSpec((1, Q_TILE, n), lambda c, b, q: (c, q, 0)),
+        pl.BlockSpec((1, q_tile, n), lambda c, b, q: (c, q, 0)),
     ]
-    out_spec = pl.BlockSpec((1, B_TILE, Q_TILE), lambda c, b, q: (c, b, q))
+    out_spec = pl.BlockSpec((1, B_TILE, q_tile), lambda c, b, q: (c, b, q))
     out_shape = jax.ShapeDtypeStruct((csz, b_pad, q_pad), jnp.int32)
     if not with_clip:
         out = pl.pallas_call(
@@ -300,7 +315,7 @@ def rnl_fire_times_compact(times: jax.Array, weights: jax.Array, *,
     b2, qsz, s2 = weights.shape
     assert bsz == b2 and s == s2, (times.shape, weights.shape)
     b_pad = common.round_up(bsz, B_TILE)
-    q_pad = common.round_up(qsz, Q_TILE)
+    q_pad, q_tile = _q_tiling(qsz)
     times_p = jnp.pad(times, ((0, b_pad - bsz), (0, 0)),
                       constant_values=int(NO_SPIKE))
     weights_p = jnp.pad(weights, ((0, b_pad - bsz), (0, q_pad - qsz),
@@ -309,13 +324,13 @@ def rnl_fire_times_compact(times: jax.Array, weights: jax.Array, *,
         jnp.where(times_p[:, None, :] < t_steps,
                   times_p[:, None, :] + weights_p, 0), t_steps, threshold)
 
-    grid = (b_pad // B_TILE, q_pad // Q_TILE)
+    grid = (b_pad // B_TILE, q_pad // q_tile)
     in_specs = [
         _smem_scalar_spec(),
         pl.BlockSpec((B_TILE, s), lambda b, q: (b, 0)),
-        pl.BlockSpec((B_TILE, Q_TILE, s), lambda b, q: (b, q, 0)),
+        pl.BlockSpec((B_TILE, q_tile, s), lambda b, q: (b, q, 0)),
     ]
-    out_spec = pl.BlockSpec((B_TILE, Q_TILE), lambda b, q: (b, q))
+    out_spec = pl.BlockSpec((B_TILE, q_tile), lambda b, q: (b, q))
     out = pl.pallas_call(
         functools.partial(_rnl_compact_kernel, threshold=threshold, k=k),
         out_shape=jax.ShapeDtypeStruct((b_pad, q_pad), jnp.int32),

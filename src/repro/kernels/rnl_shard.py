@@ -43,7 +43,7 @@ def _mesh_specs(n_columns: int, batch: int):
     """(mesh, column-axis entry, batch-axis entry) for a column-stacked
     launch, or raise if the shard_map path cannot serve this shape."""
     am = compat.get_abstract_mesh()
-    if am is None or not am.axis_names:
+    if not am.axis_names:
         raise ValueError(
             "no active mesh — call the plain kernels in rnl_neuron")
     col = sharding_specs.TNN_COLUMN_AXIS

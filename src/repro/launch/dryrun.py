@@ -180,8 +180,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax 0.4.x: [dict] per module
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
     # trip-count-aware accounting (XLA cost_analysis counts while bodies
     # ONCE — scan-over-layers under-reports by ~n_layers; hlo_cost fixes

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.data import pipeline as DP
+from repro.launch import mesh as mesh_lib
 from repro.optim import grad_compression as GC
 from repro.optim.optimizers import AdamWConfig
 from repro.sharding import specs as SH
@@ -75,7 +76,7 @@ def main():
     if jax.device_count() > 1:
         model_par = min(16, jax.device_count())
         data_par = jax.device_count() // model_par
-        mesh = jax.make_mesh((data_par, model_par), ("data", "model"))
+        mesh = mesh_lib.make_dev_mesh(data_par, model_par)
         state_shape = jax.eval_shape(
             lambda: TL.init_train_state(jax.random.PRNGKey(0), cfg, tcfg))
         st_sh = SH.param_shardings(state_shape, mesh,

@@ -200,7 +200,7 @@ def moe_apply_ep(p, x: jax.Array, cfg: MoEConfig, fsdp: bool = False
     from jax.sharding import PartitionSpec as P
     from repro.sharding import compat
     am = compat.get_abstract_mesh()
-    names = set(am.axis_names) if am is not None else set()
+    names = set(am.axis_names)
     if "model" not in names or cfg.n_experts % am.shape["model"]:
         return moe_apply(p, x, cfg)
     m_size = am.shape["model"]

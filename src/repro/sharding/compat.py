@@ -1,62 +1,35 @@
-"""jax version compatibility for the mesh/shard_map API split.
+"""The jax mesh/shard_map API in one place.
 
-The distribution layer targets the jax>=0.5 ambient-mesh API
-(``jax.set_mesh`` / ``jax.sharding.get_abstract_mesh`` / ``jax.shard_map``)
-but must run on 0.4.x, where the equivalents are the ``Mesh`` context
-manager, the thread-resources physical mesh, and
-``jax.experimental.shard_map``. Import these wrappers instead of touching
-either API directly.
+The distribution layer uses the ambient-mesh API (``jax.set_mesh`` /
+``jax.sharding.get_abstract_mesh`` / ``jax.shard_map``) and the tracer
+check through these wrappers, so a future jax API move is a one-file edit.
 """
 
 from __future__ import annotations
 
 import jax
 
-# jax.core.Tracer is deprecated (removed on the CI matrix's "latest jax"
-# leg); the private path is stable across every version we support and
-# avoids the DeprecationWarning the public alias emits on 0.5+.
-try:
-    from jax._src.core import Tracer as _Tracer
-except Exception:  # pragma: no cover - future jax reshuffles
-    _Tracer = jax.core.Tracer
-
 
 def is_tracer(x) -> bool:
     """True when ``x`` is a jax tracer (host-side measurement impossible).
 
-    The version-stable replacement for ``isinstance(x, jax.core.Tracer)``
-    — use this everywhere host-side policy code needs to branch on
-    concreteness (density measurement, compaction width selection).
+    Host-side policy code branches on concreteness through this
+    (density measurement, compaction width selection).
     """
-    return isinstance(x, _Tracer)
+    return isinstance(x, jax.core.Tracer)
 
 
 def get_abstract_mesh():
-    """The active mesh (entered via :func:`set_mesh`) or None.
-
-    jax>=0.5 returns the abstract mesh; 0.4.x the physical one — both
-    expose the ``axis_names`` / ``shape`` surface the callers use.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as _mesh
-    m = _mesh.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The ambient mesh (entered via :func:`set_mesh`); empty
+    (``axis_names == ()``) when none is active."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh):
     """Context manager activating ``mesh`` for ambient-mesh lookups."""
-    fn = getattr(jax, "set_mesh", None)
-    # a 0.4.x Mesh is itself the context manager
-    return fn(mesh) if fn is not None else mesh
+    return jax.set_mesh(mesh)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -206,7 +206,7 @@ def ambient_fit(dim: int, entry: Axis) -> Axis:
     (:mod:`repro.kernels.rnl_shard`), so the two can never disagree about
     a tensor's layout."""
     am = compat.get_abstract_mesh()
-    if am is None or not am.axis_names or entry is None:
+    if not am.axis_names or entry is None:
         return None
     names = set(am.axis_names)
     entry_t = entry if isinstance(entry, tuple) else (entry,)
@@ -223,12 +223,18 @@ def maybe_wsc(x, *spec):
     """with_sharding_constraint that degrades to identity when the named
     axes are absent (CPU unit tests, single-device benches). ``spec``
     entries are axis names, tuples of axis names, or None; axes that do
-    not divide the corresponding dim are dropped (:func:`ambient_fit`)."""
-    am = compat.get_abstract_mesh()
-    if am is None or not am.axis_names:
+    not divide the corresponding dim are dropped (:func:`ambient_fit`).
+
+    Under jit the constraint binds to the ambient abstract mesh. An eager
+    (concrete) array is placed on the concrete mesh ``set_mesh`` entered:
+    it may still sit on one device, and a bare PartitionSpec cannot
+    reshard a single-device array."""
+    if not compat.get_abstract_mesh().axis_names:
         return x
     resolved = P(*(ambient_fit(d, e) for d, e in zip(x.shape, spec)))
-    return jax.lax.with_sharding_constraint(x, resolved)
+    if compat.is_tracer(x):
+        return jax.lax.with_sharding_constraint(x, resolved)
+    return jax.device_put(x, NamedSharding(jax.sharding.get_mesh(), resolved))
 
 
 def dp_spec_names() -> tuple:
@@ -265,7 +271,7 @@ def tnn_column_size() -> int:
     divide for the shard_map Pallas fast path to tile it
     (:func:`repro.core.neuron.pallas_shardable`)."""
     am = compat.get_abstract_mesh()
-    if am is None or TNN_COLUMN_AXIS not in (am.axis_names or ()):
+    if TNN_COLUMN_AXIS not in am.axis_names:
         return 1
     return int(am.shape[TNN_COLUMN_AXIS])
 

@@ -32,6 +32,7 @@ def test_moe_ep_dispatch_matches_pjit_dispatch():
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs.registry import get_config
+        from repro.launch.mesh import make_dev_mesh
         from repro.models import moe as M, transformer as T
         from repro.sharding import compat
 
@@ -44,7 +45,7 @@ def test_moe_ep_dispatch_matches_pjit_dispatch():
         ref_out, ref_aux = jax.jit(
             lambda p, x: M.moe_apply(p, x, mcfg))(p, x)
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_dev_mesh(2, 4)
         with compat.set_mesh(mesh):
             ep_out, ep_aux = jax.jit(
                 lambda p, x: M.moe_apply_ep(p, x, mcfg))(p, x)
@@ -61,6 +62,7 @@ def test_sharded_train_step_matches_single_device():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.registry import get_config
+        from repro.launch.mesh import make_dev_mesh
         from repro.sharding import specs as SH
         from repro.train import train_loop as TL
         from repro.sharding import compat
@@ -75,7 +77,7 @@ def test_sharded_train_step_matches_single_device():
         step = TL.make_train_step(cfg, tcfg)
         _, m_ref = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_dev_mesh(2, 4)
         state_shape = jax.eval_shape(
             lambda: TL.init_train_state(jax.random.PRNGKey(0), cfg, tcfg))
         st_sh = SH.param_shardings(state_shape, mesh)

@@ -40,7 +40,8 @@ def test_unary_topk_property_counts(rows, seed):
 
 
 # ---------------------------------------------------------------- rnl_neuron
-@pytest.mark.parametrize("bsz,q,n", [(1, 1, 8), (13, 5, 16), (32, 24, 64)])
+@pytest.mark.parametrize("bsz,q,n", [(1, 1, 8), (13, 5, 16), (32, 24, 64),
+                                      (4, 200, 16)])
 @pytest.mark.parametrize("k", [None, 2, 4])
 def test_rnl_matches_oracle(bsz, q, n, k):
     kt, kw = jax.random.split(jax.random.PRNGKey(bsz * n))

@@ -71,6 +71,31 @@ def test_serve_rejects_empty_prompt_and_bad_slots(lm):
         lm.serve([np.ones((2,), np.int32)], max_new_tokens=2, n_slots=0)
 
 
+@pytest.mark.parametrize("n_slots,rows", [(1, 8), (3, 8), (8, 8), (9, 16)])
+def test_decode_batch_padded_to_row_bucket(lm, n_slots, rows):
+    """Every slot count runs the decode step at a whole number of
+    ROW_BUCKET rows, so slot counts up to 8 share one compiled program
+    (on the TPU a differently shaped matmul may round a row differently)
+    and the one-slot oracle runs the same program as the batched serve."""
+    from repro.serve import engine as E
+
+    assert E.ROW_BUCKET == 8
+    seen = []
+    step = lm._step
+
+    def spy(p, st, t):
+        seen.append(t.shape)
+        return step(p, st, t)
+
+    prompts = _prompts((2, 3), lm.cfg.vocab_size, seed=3)
+    lm._step = spy
+    try:
+        lm.serve(prompts, max_new_tokens=2, n_slots=n_slots)
+    finally:
+        lm._step = step
+    assert seen and set(seen) == {(rows, 1)}
+
+
 def test_per_slot_state_shapes_and_reset():
     """per_slot_state vectorises cache positions; reset_slots zeroes only
     the freed rows."""

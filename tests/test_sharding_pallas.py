@@ -104,14 +104,22 @@ def test_use_interpret_explicit_override(monkeypatch):
 def test_sharded_pallas_network_bit_exact_property():
     """network.forward with pallas/pallas_compact layers on the (2, 4)
     mesh == the single-device scan reference, over random sparse draws
-    plus the all-silent and fully-dense edges; the ragged C=5 net takes
-    the replication fallback and must agree too."""
+    plus the all-silent and fully-dense edges; on the ragged C=5 net an
+    explicit Pallas layer raises, and ``auto`` (on a TPU backend) takes the
+    replication fallback and must agree too."""
     print(_run("""
-        for backend in ("pallas", "pallas_compact"):
+        for backend in ("pallas", "pallas_compact", "auto"):
             for cfg0, ps in ((net, params), (odd, podd)):
                 bnet = network.make_network(
                     [dataclasses.replace(lc, backend=backend)
                      for lc in cfg0.layers])
+                if cfg0 is odd and backend != "auto":
+                    with compat.set_mesh(mesh):
+                        try:
+                            network.forward(ps, vodd, bnet)
+                        except ValueError:
+                            continue
+                    raise AssertionError(f"explicit {backend} with C=5")
                 draws = [sparse_volleys(np.random.default_rng(s), 8,
                                         cfg0.n_inputs) for s in range(3)]
                 draws.append(np.full((8, cfg0.n_inputs), NS, np.int32))
@@ -125,12 +133,18 @@ def test_sharded_pallas_network_bit_exact_property():
                 for volleys in draws:
                     rres = network.forward(ps, volleys, snet)
                     ref, ref_win = np.asarray(rres.out), rres.winners
-                    with compat.set_mesh(mesh):
-                        vs = jax.device_put(
-                            volleys, network.data_sharding(bnet, mesh,
-                                                           volleys.shape[0]))
-                        sres = network.forward(sp, vs, bnet)
-                        out, win = sres.out, sres.winners
+                    jb = jax.default_backend
+                    if backend == "auto":   # TPU policy: pallas or fallback
+                        jax.default_backend = lambda: 'tpu'
+                    try:
+                        with compat.set_mesh(mesh):
+                            vs = jax.device_put(
+                                volleys, network.data_sharding(
+                                    bnet, mesh, volleys.shape[0]))
+                            sres = network.forward(sp, vs, bnet)
+                            out, win = sres.out, sres.winners
+                    finally:
+                        jax.default_backend = jb
                     np.testing.assert_array_equal(np.asarray(out), ref)
                     for w_ref, w_sh in zip(ref_win, win):
                         np.testing.assert_array_equal(np.asarray(w_sh),
@@ -313,4 +327,75 @@ def test_sharded_pipelined_pallas_bit_exact():
                     np.testing.assert_array_equal(np.asarray(w_sh),
                                                   np.asarray(w_ref))
         print('SHARDED_PIPELINED_PALLAS_OK')
+    """))
+
+
+def test_explicit_pallas_raises_under_nondividing_mesh():
+    """An explicit Pallas request that the 4-device column mesh cannot
+    tile (C=5, or a 2-D bank) raises — at the bank, the network and the
+    serve engine — instead of quietly running a jnp engine."""
+    print(_run("""
+        from repro.serve import tnn_engine
+        mesh4 = SH.tnn_mesh(n_column=4)               # (data=1, column=4)
+        assert mesh4.devices.size == 4
+        cfgn = l1.neuron_config()
+        times_rf = jnp.swapaxes(jnp.asarray(vodd)[:, odd.layers[0].rf_index()],
+                                0, 1)                  # (5, B, rf)
+        w = jnp.round(podd[0]).astype(jnp.int32)
+
+        def raises(fn):
+            try:
+                fn()
+            except ValueError as e:
+                assert "backend='auto'" in str(e), e
+            else:
+                raise AssertionError('explicit pallas did not raise')
+
+        for backend in ('pallas', 'pallas_compact'):
+            bnet = network.make_network(
+                [dataclasses.replace(lc, backend=backend)
+                 for lc in odd.layers])
+            with compat.set_mesh(mesh4):
+                raises(lambda: neuron.fire_times_bank(times_rf, w, cfgn,
+                                                      backend=backend))
+                raises(lambda: neuron.fire_times_bank(times_rf[0], w[0],
+                                                      cfgn, backend=backend))
+                raises(lambda: network.forward(podd, vodd, bnet))
+            raises(lambda: tnn_engine.TNNEngine(
+                podd, odd, tnn_engine.TNNServeConfig(n_slots=2,
+                                                     backend=backend),
+                mesh=mesh4))
+        print('EXPLICIT_PALLAS_RAISES_OK')
+    """))
+
+
+def test_auto_degrades_under_nondividing_mesh():
+    """``auto`` on a TPU backend under the same 4-device mesh with C=5
+    falls back to a jnp engine and stays bit-exact; with dividing
+    columns it keeps the Pallas kernel."""
+    print(_run("""
+        from repro.serve import tnn_engine
+        mesh4 = SH.tnn_mesh(n_column=4)
+        ref = np.asarray(network.forward(podd, vodd, odd).out)
+        jb, jax.default_backend = jax.default_backend, lambda: 'tpu'
+        try:
+            pol = policy.default_policy()
+            with compat.set_mesh(mesh4):
+                res = pol.resolve('auto', column_counts=5)
+                assert res.engine in ('closed_form', 'event'), res
+                assert pol.resolve('auto', column_counts=8).engine == \\
+                    'pallas'
+                got = network.forward(podd, vodd, odd).out
+            np.testing.assert_array_equal(np.asarray(got), ref)
+            eng = tnn_engine.TNNEngine(
+                podd, odd, tnn_engine.TNNServeConfig(n_slots=4), mesh=mesh4)
+            out = eng.serve([vodd[:3], vodd[3:]])
+        finally:
+            jax.default_backend = jb
+        for s, r in zip([vodd[:3], vodd[3:]], out):
+            np.testing.assert_array_equal(
+                tnn_engine.reference_outputs(podd, odd, s), r)
+        st = eng.stats()
+        assert 'steps_pallas' not in st and st['n_steps'] > 0, st
+        print('AUTO_DEGRADES_OK')
     """))

@@ -151,7 +151,7 @@ def test_ambient_fit_resolution(monkeypatch):
     assert SH.ambient_fit(6, None) is None
     assert SH.ambient_fit(8, ("pod", "data")) == "data"  # mesh has no pod
     assert SH.ambient_fit(8, ("data", "column")) == ("data", "column")
-    monkeypatch.setattr(compat, "get_abstract_mesh", lambda: None)
+    monkeypatch.setattr(compat, "get_abstract_mesh", lambda: FakeMesh({}))
     assert SH.ambient_fit(8, "column") is None       # no mesh -> identity
 
 
@@ -171,6 +171,8 @@ def test_maybe_wsc_resolves_dims_in_order(monkeypatch):
         return x
 
     monkeypatch.setattr(jax.lax, "with_sharding_constraint", fake_wsc)
+    # the Leaf stands in for a traced value (the in-jit constraint path)
+    monkeypatch.setattr(compat, "is_tracer", lambda x: True)
     x = Leaf(8, 6, 7)
     assert SH.maybe_wsc(x, "column", "data", None) is x
     assert captured["spec"] == P("column", "data", None)

@@ -135,11 +135,11 @@ def test_sharded_engine_serve_bit_exact():
 
 def test_pallas_mesh_capability_model():
     """Per-kernel mesh capability (DESIGN.md §6.4): under an active mesh
-    the Pallas engines survive exactly when the column stack tiles the
-    ``column`` axis (shard_map fast path, kernels/rnl_shard); 2-D banks,
-    unknown shapes, and non-dividing C keep the replication-era
-    degradation to the bit-exact jnp engines — and serve stats() records
-    whichever engine actually ran."""
+    the Pallas engines run exactly when the column stack tiles the
+    ``column`` axis (shard_map fast path, kernels/rnl_shard). For 2-D
+    banks, unknown shapes, and non-dividing C an explicit Pallas request
+    raises, while ``auto`` degrades to the bit-exact jnp engines — and
+    serve stats() records whichever engine actually ran."""
     print(_run("""
         cfgn = l1.neuron_config()
         times_rf = jnp.swapaxes(jnp.asarray(v)[:, l1.rf_index()], 0, 1)
@@ -155,15 +155,16 @@ def test_pallas_mesh_capability_model():
             assert pol.resolve(
                 'pallas_compact', column_counts=(8, 4)).engine == \\
                 'pallas_compact'
-            # unknown / non-dividing shapes keep the old degradation
-            assert pol.resolve('pallas').engine == 'closed_form'
-            assert pol.resolve('pallas', column_counts=5).engine == \\
-                'closed_form'
-            assert pol.resolve('pallas_compact', column_counts=5).engine \\
-                == 'event'
-            # degradation never rewrites the request
-            assert pol.resolve('pallas', column_counts=5).requested == \\
-                'pallas'
+            # unknown / non-dividing shapes: an explicit Pallas request
+            # raises instead of switching engines behind the caller's back
+            for name, counts in (('pallas', None), ('pallas', 5),
+                                 ('pallas_compact', 5)):
+                try:
+                    pol.resolve(name, column_counts=counts)
+                except ValueError as e:
+                    assert "backend='auto'" in str(e), e
+                else:
+                    raise AssertionError((name, counts))
             # every engine stays bit-exact through the dispatch
             for backend in ('pallas', 'pallas_compact', 'auto'):
                 got = neuron.fire_times_bank(times_rf, w, cfgn,
@@ -196,12 +197,27 @@ def test_pallas_mesh_capability_model():
                 tnn_engine.reference_outputs(params, net, s), r)
         st = eng.stats()
         assert st['steps_pallas'] > 0 and 'steps_closed_form' not in st, st
-        # non-dividing C=5: replication fallback keeps the degradation row
-        engo = tnn_engine.TNNEngine(
-            podd, odd,
-            tnn_engine.TNNServeConfig(n_slots=2, backend='pallas'),
-            mesh=mesh)
-        for s, r in zip([vodd[:2]], engo.serve([vodd[:2]])):
+        # non-dividing C=5: an explicit pallas engine refuses to start;
+        # auto on "TPU" takes the replication fallback and records it
+        try:
+            tnn_engine.TNNEngine(
+                podd, odd,
+                tnn_engine.TNNServeConfig(n_slots=2, backend='pallas'),
+                mesh=mesh)
+        except ValueError:
+            pass
+        else:
+            raise AssertionError('explicit pallas with C=5 must raise')
+        jb, jax.default_backend = jax.default_backend, lambda: 'tpu'
+        try:
+            engo = tnn_engine.TNNEngine(
+                podd, odd,
+                tnn_engine.TNNServeConfig(n_slots=2, policy='density'),
+                mesh=mesh)
+            served = engo.serve([vodd[:2]])
+        finally:
+            jax.default_backend = jb
+        for s, r in zip([vodd[:2]], served):
             np.testing.assert_array_equal(
                 tnn_engine.reference_outputs(podd, odd, s), r)
         sto = engo.stats()
@@ -216,8 +232,9 @@ def test_sharded_pipelined_forward_bit_exact():
     single-device
     barriered reference for every jnp engine and micro-batch split (incl.
     ragged 8 % 3 != 0 and M > B) — the §5.4 schedule composes with the
-    §6.4/§6.5 placement without changing a spike time. Covers the jax
-    0.4.x while-loop carry miscompile the full unroll sidesteps."""
+    §6.4/§6.5 placement without changing a spike time. The tick scan runs
+    rolled under the mesh, so this also guards the cross-layer stage carry
+    of the while loop against an SPMD layout miscompile."""
     print(_run("""
         from repro.serve import tnn_engine
         for backend in ("scan", "closed_form", "event"):
