@@ -34,7 +34,7 @@ AUDIT = """
 
     def buggy_wsc(x, *spec):
         am = sharding_specs.compat.get_abstract_mesh()
-        if am is None or not am.axis_names:
+        if not am.axis_names:
             return x
         resolved = P(*(sharding_specs.ambient_fit(d, e)
                        for d, e in zip(spec, x.shape)))
@@ -62,7 +62,7 @@ CLI_BUGGY = """
 
     def buggy_wsc(x, *spec):
         am = sharding_specs.compat.get_abstract_mesh()
-        if am is None or not am.axis_names:
+        if not am.axis_names:
             return x
         resolved = P(*(sharding_specs.ambient_fit(d, e)
                        for d, e in zip(spec, x.shape)))
