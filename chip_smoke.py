@@ -15,7 +15,8 @@ calls, with everything generated from ``--seed``:
           the learning-off engine's at the same pre-update weights.
   lm      ``internlm2-1.8b`` at its published widths (random weights from
           the seed) through ``serve.Engine``: greedy tokens of a batched,
-          slot-refilling serve must equal the per-request oracle.
+          slot-refilling serve must equal each request served alone
+          through the same slot count.
 
 ``--four-chips`` runs only the 4-chip phase: the ``tnn`` network served by
 ``TNNEngine(mesh=specs.tnn_mesh(n_column=4), backend="pallas")`` (the
@@ -102,11 +103,11 @@ def _serve(eng, streams):
 
 
 def _check_lowered_kernel(eng, batch) -> None:
-    """The engine's step lowers to Mosaic kernels, not the interpreter."""
-    from repro.core import network
-
-    text = jax.jit(lambda p, v: network.forward(p, v, eng.net).out).lower(
-        eng.params, batch).as_text()
+    """The jitted step ``TNNEngine.step`` ran for ``pallas`` lowers to
+    Mosaic kernels, not the interpreter."""
+    no_carry = (None,) * len(eng.net.layers)
+    text = eng._fwd_for("pallas").lower(
+        eng.params, eng._place(batch), eng._place_carry(no_carry)).as_text()
     if "tpu_custom_call" not in text:
         raise AssertionError("lowered TNN step holds no tpu_custom_call")
 
@@ -146,8 +147,8 @@ def phase_tnn(seed: int) -> None:
 
     eng = tnn_engine.TNNEngine(
         params, net, tnn_engine.TNNServeConfig(n_slots=128, backend="auto"))
-    _check_lowered_kernel(eng, np.concatenate(streams)[:128])
     reqs, first_s, drain_s = _serve(eng, streams)
+    _check_lowered_kernel(eng, np.concatenate(streams)[:128])
     st = eng.stats()
     steps = _engine_steps(st)
     _log("tnn", f"steps {int(st['n_steps'])}, engines {steps}, first step "
@@ -240,10 +241,12 @@ def phase_lm(seed: int) -> None:
          f"vocab {cfg.vocab_size}, {cfg.param_count() / 1e9:.2f}B params "
          f"({cfg.dtype}); init {init_s:.2f} s; prompt lengths "
          f"{[len(p) for p in prompts]}")
-    # the per-request oracle of benchmarks/bench_serve_lm.py: each prompt
-    # served alone, then all of them through fewer slots than requests
+    # the per-request oracle: each prompt served alone, then all of them
+    # through fewer slots than requests. Alone means alone in the same
+    # slot count: on a TPU a one-row decode matmul rounds differently from
+    # a wider one (DESIGN.md section 5.2, experiments/lm_row_rounding.py)
     t0 = time.perf_counter()
-    oracle = [eng.serve([p], max_new)[0] for p in prompts]
+    oracle = [eng.serve([p], max_new, n_slots=n_slots)[0] for p in prompts]
     oracle_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     outs = eng.serve(prompts, max_new, n_slots=n_slots)

@@ -15,8 +15,8 @@ re-fills from the pending queue at the top of the next step, mid-flight,
 while the other rows keep decoding. Because attention rows are independent,
 a request's sampled tokens are identical whatever the batch composition —
 continuous batching changes throughput, never outputs (greedy; pinned by
-tests/test_serve_lm.py). The decode batch is padded to whole
-``ROW_BUCKET``s, so slot counts up to 8 run one compiled program.
+tests/test_serve_lm.py). On a TPU this holds between slot counts of 2 or
+more only: a one-row decode matmul rounds differently (DESIGN.md §5.2).
 
 Greedy and temperature sampling. Families whose decode state is not a
 positional KV cache (ssm / hybrid recurrences, audio's per-request encoder
@@ -38,14 +38,6 @@ from repro.configs.base import ModelConfig
 from repro.data import tokenizer as tok
 from repro.models import transformer as T
 from repro.serve.slots import SlotPool
-
-#: decode batches are padded to a multiple of this many rows (the TPU
-#: sublane count). On the TPU the compiled matmuls may round a row
-#: differently at another batch size, so a request's greedy tokens are
-#: identical only across slot counts that share one row count; padding
-#: makes every ``n_slots`` up to 8 share one program. Pad rows are free
-#: rows: never admitted, fed the in-vocab filler.
-ROW_BUCKET = 8
 
 
 @dataclasses.dataclass
@@ -118,10 +110,8 @@ class Engine:
         b = len(prompts) if n_slots is None else int(n_slots)
         if b < 1:
             raise ValueError(f"need at least one slot, got {b}")
-        rows = -(-b // ROW_BUCKET) * ROW_BUCKET
         state = T.per_slot_state(
-            T.init_serve_state(self.params, self.cfg, rows, scfg.max_len),
-            rows)
+            T.init_serve_state(self.params, self.cfg, b, scfg.max_len), b)
 
         def on_admit(idx: int, entry) -> None:
             del idx
@@ -147,14 +137,14 @@ class Engine:
             if continuous or pool.n_live == 0:
                 admitted = pool.admit()
                 if admitted:
-                    free = np.zeros((rows,), bool)
+                    free = np.zeros((b,), bool)
                     for idx, _ in admitted:
                         free[idx] = True
                     # re-filled rows restart at cache position 0; stale
                     # K/V above it is hidden by the pos-derived validity
                     # mask (attention._cache_valid), so no clearing
                     state = self._reset(state, jnp.asarray(free))
-            tokens = np.full((rows, 1), self._fill, np.int32)
+            tokens = np.full((b, 1), self._fill, np.int32)
             for idx, entry in pool.live():
                 req, st = entry.item, entry.state
                 tokens[idx, 0] = (req.prompt[st["fed"]]

@@ -1,7 +1,8 @@
 """The jax mesh/shard_map API in one place.
 
 The distribution layer uses the ambient-mesh API (``jax.set_mesh`` /
-``jax.sharding.get_abstract_mesh`` / ``jax.shard_map``) and the tracer
+``jax.sharding.get_abstract_mesh`` / ``jax.sharding.get_mesh`` /
+``jax.shard_map``) and the tracer
 check through these wrappers, so a future jax API move is a one-file edit.
 """
 
@@ -23,6 +24,12 @@ def get_abstract_mesh():
     """The ambient mesh (entered via :func:`set_mesh`); empty
     (``axis_names == ()``) when none is active."""
     return jax.sharding.get_abstract_mesh()
+
+
+def get_mesh():
+    """The concrete mesh :func:`set_mesh` entered (device placement of
+    eager arrays)."""
+    return jax.sharding.get_mesh()
 
 
 def set_mesh(mesh):

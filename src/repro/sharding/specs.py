@@ -234,7 +234,7 @@ def maybe_wsc(x, *spec):
     resolved = P(*(ambient_fit(d, e) for d, e in zip(x.shape, spec)))
     if compat.is_tracer(x):
         return jax.lax.with_sharding_constraint(x, resolved)
-    return jax.device_put(x, NamedSharding(jax.sharding.get_mesh(), resolved))
+    return jax.device_put(x, NamedSharding(compat.get_mesh(), resolved))
 
 
 def dp_spec_names() -> tuple:
