@@ -1,0 +1,16 @@
+"""The chip benchmark's harness: the yardstick that later changes may not edit.
+
+Modules, from the entry point down:
+
+  spec       finds a cell and the files it names (configs/, traffic/, metrics/)
+  traffic    the one general traffic generator: GRF volley pool and schedules
+  grf        numpy copy of the GRF front end that encodes the volleys
+  system     the only module that imports the program under test
+  loop       drives the program's submit/step loop through the measured window
+  reference  plain jax.numpy TNN (forward, 1-WTA, STDP), independent of the program
+  check      the comparison that decides ``correct``
+  trace      reduction of a profiler trace to device busy time, kernel time, gaps
+  work       operations and bytes the RNL algorithm needs, from the shapes
+  peaks      published chip peaks keyed by ``device_kind``
+  runner     one run of one cell: set-up, window, metrics, check, result line
+"""
