@@ -224,29 +224,35 @@ def layer_forward(weights: jax.Array, volleys: jax.Array, cfg: TNNLayer,
         # pin the carry like a stage buffer: batch over DP, output lines
         # over "column" (sharding.specs.tnn_carry_axes; identity w/o mesh).
         carry = sharding_specs.maybe_wsc(carry, *_CARRY)
-    w_int = jnp.round(weights).astype(jnp.int32)
-    times_rf = _gather_rf(volleys, cfg, carry)                # (C, B, rft)
-    # under an active mesh, pin the (columns, neurons) plane: columns over
-    # "column", batch over DP (DESIGN.md §6.4; identity without a mesh).
-    # This is also the exact layout the shard_map Pallas fast path consumes
-    # (kernels/rnl_shard mirrors these entries via specs.ambient_fit), so
-    # when fire_times_bank takes that path no resharding happens here.
-    times_rf = sharding_specs.maybe_wsc(times_rf, _COL, _DP, None)
-    fire = neuron.fire_times_bank(times_rf, w_int, cfg.neuron_config(),
-                                  backend=cfg.backend,
-                                  n_active_max=cfg.n_active_max,
-                                  policy=cfg.policy)              # (C, B, Q)
-    fire = sharding_specs.maybe_wsc(fire, _COL, _DP, None)
-    fire = jnp.swapaxes(fire, 0, 1)                           # (B, C, Q)
-    # vectorized 1-WTA over the (B, C) plane; argmin's first-minimum rule
-    # is the tie-break-to-lowest-index priority encoder.
-    any_fire = jnp.any(coding.is_spike(fire), axis=-1)        # (B, C)
-    winners = jnp.argmin(fire, axis=-1).astype(jnp.int32)
-    winners = jnp.where(any_fire, winners, -1)
-    winners = sharding_specs.maybe_wsc(winners, _DP, _COL)
-    lane = jnp.arange(cfg.n_neurons, dtype=jnp.int32)
-    out = jnp.where(lane == winners[..., None], fire, coding.NO_SPIKE)
-    out = sharding_specs.maybe_wsc(out, _DP, _COL, None)
+    # named scopes group the device ops by stage in a profile (op names
+    # carry the scope); they change nothing that is computed
+    with jax.named_scope("gather"):
+        w_int = jnp.round(weights).astype(jnp.int32)
+        times_rf = _gather_rf(volleys, cfg, carry)            # (C, B, rft)
+        # under an active mesh, pin the (columns, neurons) plane: columns
+        # over "column", batch over DP (DESIGN.md §6.4; identity without a
+        # mesh). This is also the exact layout the shard_map Pallas fast
+        # path consumes (kernels/rnl_shard mirrors these entries via
+        # specs.ambient_fit), so when fire_times_bank takes that path no
+        # resharding happens here.
+        times_rf = sharding_specs.maybe_wsc(times_rf, _COL, _DP, None)
+    with jax.named_scope("bank"):
+        fire = neuron.fire_times_bank(times_rf, w_int, cfg.neuron_config(),
+                                      backend=cfg.backend,
+                                      n_active_max=cfg.n_active_max,
+                                      policy=cfg.policy)          # (C, B, Q)
+        fire = sharding_specs.maybe_wsc(fire, _COL, _DP, None)
+    with jax.named_scope("wta"):
+        fire = jnp.swapaxes(fire, 0, 1)                       # (B, C, Q)
+        # vectorized 1-WTA over the (B, C) plane; argmin's first-minimum
+        # rule is the tie-break-to-lowest-index priority encoder.
+        any_fire = jnp.any(coding.is_spike(fire), axis=-1)    # (B, C)
+        winners = jnp.argmin(fire, axis=-1).astype(jnp.int32)
+        winners = jnp.where(any_fire, winners, -1)
+        winners = sharding_specs.maybe_wsc(winners, _DP, _COL)
+        lane = jnp.arange(cfg.n_neurons, dtype=jnp.int32)
+        out = jnp.where(lane == winners[..., None], fire, coding.NO_SPIKE)
+        out = sharding_specs.maybe_wsc(out, _DP, _COL, None)
     if single:
         return out[0], winners[0]
     return out, winners
@@ -269,28 +275,31 @@ def layer_step(weights: jax.Array, volleys: jax.Array, cfg: TNNLayer,
         if carry is not None and carry.ndim == 1:
             carry = carry[None, :]
     out_times, winners = layer_forward(weights, volleys, cfg, carry)
-    times_rf = _gather_rf(volleys, cfg, carry)                # (C, B, rft)
-    times_rf = sharding_specs.maybe_wsc(times_rf, _COL, _DP, None)
-    out_cb = jnp.swapaxes(out_times, 0, 1)                    # (C, B, Q)
-    win_cb = jnp.swapaxes(winners, 0, 1)                      # (C, B)
-    ckeys = (jax.random.split(key, cfg.n_columns)
-             if key is not None else None)
+    with jax.named_scope("stdp"):
+        times_rf = _gather_rf(volleys, cfg, carry)            # (C, B, rft)
+        times_rf = sharding_specs.maybe_wsc(times_rf, _COL, _DP, None)
+        out_cb = jnp.swapaxes(out_times, 0, 1)                # (C, B, Q)
+        win_cb = jnp.swapaxes(winners, 0, 1)                  # (C, B)
+        ckeys = (jax.random.split(key, cfg.n_columns)
+                 if key is not None else None)
 
-    def one_column(w, in_t, out_t, win, ck):
-        return stdp.stdp_update_column_minibatch(
-            w, in_t, out_t, win, cfg.stdp, ck,
-            reduction=cfg.stdp_reduction)
+        def one_column(w, in_t, out_t, win, ck):
+            return stdp.stdp_update_column_minibatch(
+                w, in_t, out_t, win, cfg.stdp, ck,
+                reduction=cfg.stdp_reduction)
 
-    if ckeys is None:
-        new_w = jax.vmap(lambda w, t, o, g: one_column(w, t, o, g, None))(
-            weights, times_rf, out_cb, win_cb)
-    else:
-        new_w = jax.vmap(one_column)(weights, times_rf, out_cb, win_cb,
-                                     ckeys)
-    # pin the updated stack where tnn_param_pspec placed the input stack
-    # (identity without a mesh): a learning service's weights never drift
-    # off their column shards across steps (DESIGN.md §6.4).
-    new_w = sharding_specs.maybe_wsc(new_w, *_PARAM)
+        if ckeys is None:
+            new_w = jax.vmap(
+                lambda w, t, o, g: one_column(w, t, o, g, None))(
+                weights, times_rf, out_cb, win_cb)
+        else:
+            new_w = jax.vmap(one_column)(weights, times_rf, out_cb, win_cb,
+                                         ckeys)
+        # pin the updated stack where tnn_param_pspec placed the input
+        # stack (identity without a mesh): a learning service's weights
+        # never drift off their column shards across steps (DESIGN.md
+        # §6.4).
+        new_w = sharding_specs.maybe_wsc(new_w, *_PARAM)
     return new_w, out_times, winners
 
 

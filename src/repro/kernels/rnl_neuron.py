@@ -215,6 +215,7 @@ def rnl_fire_times(times: jax.Array, weights: jax.Array, *, t_steps: int,
             functools.partial(_rnl_kernel, threshold=threshold, k=k),
             out_shape=jax.ShapeDtypeStruct((b_pad, q_pad), jnp.int32),
             grid=grid, in_specs=in_specs, out_specs=out_spec,
+            name="rnl_fire_times",
             interpret=common.use_interpret(),
         )(t_hi, times_p, weights_p)
         return out[:bsz, :qsz]
@@ -223,6 +224,7 @@ def rnl_fire_times(times: jax.Array, weights: jax.Array, *, t_steps: int,
         out_shape=[jax.ShapeDtypeStruct((b_pad, q_pad), jnp.int32),
                    jax.ShapeDtypeStruct((b_pad, q_pad), jnp.int32)],
         grid=grid, in_specs=in_specs, out_specs=[out_spec, out_spec],
+        name="rnl_fire_times",
         interpret=common.use_interpret(),
     )(t_hi, times_p, weights_p)
     return fire[:bsz, :qsz], clip[:bsz, :qsz]
@@ -274,6 +276,7 @@ def rnl_fire_times_layer(times: jax.Array, weights: jax.Array, *,
             functools.partial(_rnl_layer_kernel, threshold=threshold, k=k),
             out_shape=out_shape,
             grid=grid, in_specs=in_specs, out_specs=out_spec,
+            name="rnl_fire_times_layer",
             interpret=common.use_interpret(),
         )(t_hi, times_p, weights_p)
         return out[:, :bsz, :qsz]
@@ -281,6 +284,7 @@ def rnl_fire_times_layer(times: jax.Array, weights: jax.Array, *,
         functools.partial(_rnl_layer_clip_kernel, threshold=threshold, k=k),
         out_shape=[out_shape, out_shape],
         grid=grid, in_specs=in_specs, out_specs=[out_spec, out_spec],
+        name="rnl_fire_times_layer",
         interpret=common.use_interpret(),
     )(t_hi, times_p, weights_p)
     return fire[:, :bsz, :qsz], clip[:, :bsz, :qsz]
@@ -335,6 +339,7 @@ def rnl_fire_times_compact(times: jax.Array, weights: jax.Array, *,
         functools.partial(_rnl_compact_kernel, threshold=threshold, k=k),
         out_shape=jax.ShapeDtypeStruct((b_pad, q_pad), jnp.int32),
         grid=grid, in_specs=in_specs, out_specs=out_spec,
+        name="rnl_fire_times_compact",
         interpret=common.use_interpret(),
     )(t_hi, times_p, weights_p)
     return out[:bsz, :qsz]

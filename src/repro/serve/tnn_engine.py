@@ -110,6 +110,9 @@ SPARSE_ENGINES = ("event", "pallas_compact")
 
 NO_SPIKE = int(coding.NO_SPIKE)
 
+#: a named host span on the profiler's clock (``TNNEngine.step``'s phases)
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class TNNServeConfig:
@@ -525,10 +528,11 @@ class TNNEngine:
         manager copies to host numpy synchronously and serializes on its
         own thread — the weights are immutable jax arrays, so a later
         STDP update can never leak into an in-flight save."""
-        if self._ckpt is None:
+        if self._ckpt is None or not self._ckpt.due(self.step_id):
             return
-        if self._ckpt.maybe_save(self.step_id, self._snapshot_state()):
-            self.n_snapshots += 1
+        with _span("tnn.snapshot"):
+            if self._ckpt.maybe_save(self.step_id, self._snapshot_state()):
+                self.n_snapshots += 1
 
     def checkpoint_wait(self) -> None:
         """Block until any in-flight async snapshot has published."""
@@ -748,123 +752,157 @@ class TNNEngine:
 
     def step(self) -> List[TNNRequest]:
         """One gamma cycle for every live slot; returns requests retired
-        this step (in ascending slot order)."""
+        this step (in ascending slot order).
+
+        The step and each of its phases run inside a named profiler span
+        (``tnn.step``; ``tnn.admit``, ``tnn.batch``, ``tnn.measure``,
+        ``tnn.resolve``, ``tnn.h2d``, ``tnn.dispatch``, ``tnn.fetch``,
+        ``tnn.retire``, and ``tnn.snapshot`` on the steps that snapshot),
+        so a trace puts the host's work on the device trace's clock. With
+        no profiler running a span costs about a microsecond."""
         t0 = time.perf_counter()
-        self.pool.admit()
-        live = list(self.pool.live())
-        if not live:
-            return []
-        batch = np.full((self.scfg.n_slots, self.net.n_inputs), NO_SPIKE, np.int32)
-        # per-layer recurrent carry batches from the live slots' state;
-        # free rows stay all-NO_SPIKE (silent carries are inert, like
-        # their input rows), so the batch stays shape-static
-        carry_np: CarryState = tuple(
-            np.full((self.scfg.n_slots, lc.n_outputs), NO_SPIKE, np.int32)
-            if lc.recurrent
-            else None
-            for lc in self.net.layers
-        )
-        for idx, entry in live:
-            req = entry.item
-            batch[idx] = req.volleys[req.cursor]
-            if self.stateful:
-                for c, s in zip(carry_np, entry.state):
-                    if c is not None:
-                        c[idx] = s
-        # measured batch density (host-side — the jit boundary can't see
-        # it): NO_SPIKE-padded free slots count as silent lines, which is
-        # precisely why partially-filled batches resolve to the event path.
-        # Under pipelining the same measurement lands per micro-batch, so
-        # stats() can show each stage's traffic; the step-level resolution
-        # stays whole-batch (one compiled schedule serves all stages).
-        density = float(np.mean(batch < self._t_steps))
-        if self.n_stages > 1:
-            for i, (lo, hi) in enumerate(self._stage_rows):
-                self._stage_density_sums[i] += float(np.mean(batch[lo:hi] < self._t_steps))
-        with self._mesh_scope():
-            # resolution inside the mesh scope with the network's column
-            # counts: the policy sees the mesh AND the per-kernel Pallas
-            # capability, so the Pallas engines survive when every layer's
-            # columns tile the mesh and degrade only in the replication-
-            # fallback case; Resolution.engine is what will actually run,
-            # so stats/jit-variants record the truth. The measured layer-0
-            # active count feeds both the cost ranking and the compaction
-            # bucket (width stays exact-covering: no active line drops).
-            res = self._policy.resolve(
-                self.scfg.backend, density=density,
-                max_active=self._layer0_active(batch),
-                column_counts=self._column_counts,
-                shape=self._bank_shape)
-            engine = res.engine
-            self._density_sum += density
-            self._backend_steps[engine] = self._backend_steps.get(engine, 0) + 1
-            if res.predicted_us:
-                want = min(res.predicted_us, key=res.predicted_us.__getitem__)
-                self._predicted_steps[want] = \
-                    self._predicted_steps.get(want, 0) + 1
-                for name, us in res.predicted_us.items():
-                    self._predicted_us_sum[name] = \
-                        self._predicted_us_sum.get(name, 0.0) + us
-            # sparse engines compile against a static compaction width
-            # bucketed from this batch's own receptive-field measurement
-            width = res.width if engine in SPARSE_ENGINES else None
-            if self._learn_gate():
-                # STDP step: outputs at the pre-update weights (bit-exact
-                # with the inference path), new weights advance the
-                # engine's explicit state — no recompile, and under a
-                # mesh the update stays column-sharded (layer_step pins
-                # it via specs.tnn_param_axes)
-                out_dev, carry_dev, new_params = self._fwd_for(
-                    engine, width, learn=True
-                )(
-                    self.params,
-                    self._place(batch),
-                    self._place_carry(carry_np),
-                    self._stdp_key(),
+        with jax.profiler.StepTraceAnnotation("tnn.step",
+                                              step_num=self.step_id):
+            with _span("tnn.admit"):
+                self.pool.admit()
+                live = list(self.pool.live())
+            if not live:
+                return []
+            with _span("tnn.batch"):
+                batch = np.full((self.scfg.n_slots, self.net.n_inputs),
+                                NO_SPIKE, np.int32)
+                # per-layer recurrent carry batches from the live slots'
+                # state; free rows stay all-NO_SPIKE (silent carries are
+                # inert, like their input rows), so the batch stays
+                # shape-static
+                carry_np: CarryState = tuple(
+                    np.full((self.scfg.n_slots, lc.n_outputs), NO_SPIKE,
+                            np.int32)
+                    if lc.recurrent
+                    else None
+                    for lc in self.net.layers
                 )
-                self.params = new_params
-                self.n_stdp_updates += 1
-            else:
-                out_dev, carry_dev = self._fwd_for(engine, width)(
-                    self.params, self._place(batch), self._place_carry(carry_np)
-                )
-            out = np.asarray(out_dev)
-            carry_out = tuple(
-                None if c is None else np.asarray(c) for c in carry_dev
-            )
-        retired: List[TNNRequest] = []
-        for idx, entry in live:
-            req = entry.item
-            req.backends.add(engine)
-            # copy: out[idx] is a view that would pin the whole (B, C, Q)
-            # batch array for the life of the request
-            req.outputs.append(out[idx].copy())
-            req.cursor += 1
-            if self.stateful:
-                # scatter this row's new carry back into the slot's state
-                entry.state = tuple(
-                    None if c is None else c[idx].copy() for c in carry_out
-                )
-            if req.done:
-                done_entry = self.pool.retire(idx)
-                # the final carry leaves the pool on the entry; hand it to
-                # the request so the client can continue the stream later
-                req.final_state = done_entry.state
-                # keep only the timestamps for the latency summary — holding
-                # the request (volleys + outputs + state) would grow without
-                # bound in a long-lived service
-                self._retired.append(
-                    dataclasses.replace(done_entry, item=None, state=None)
-                )
-                retired.append(req)
-        self.n_steps += 1
-        self.n_volleys += len(live)
-        # persistent counter + snapshot cadence: step_id advances AFTER
-        # the step's retirements, so a snapshot at step s commits every
-        # stream retired at-or-before s (the serve_resilient commit rule);
-        # advancing first also keeps maybe_save from firing at step 0
-        self.step_id += 1
-        self._maybe_snapshot()
+                for idx, entry in live:
+                    req = entry.item
+                    batch[idx] = req.volleys[req.cursor]
+                    if self.stateful:
+                        for c, s in zip(carry_np, entry.state):
+                            if c is not None:
+                                c[idx] = s
+            with _span("tnn.measure"):
+                # measured batch density (host-side — the jit boundary
+                # can't see it): NO_SPIKE-padded free slots count as silent
+                # lines, which is precisely why partially-filled batches
+                # resolve to the event path. Under pipelining the same
+                # measurement lands per micro-batch, so stats() can show
+                # each stage's traffic; the step-level resolution stays
+                # whole-batch (one compiled schedule serves all stages).
+                density = float(np.mean(batch < self._t_steps))
+                if self.n_stages > 1:
+                    for i, (lo, hi) in enumerate(self._stage_rows):
+                        self._stage_density_sums[i] += float(
+                            np.mean(batch[lo:hi] < self._t_steps))
+                # the measured layer-0 active count feeds both the cost
+                # ranking and the compaction bucket (width stays
+                # exact-covering: no active line drops)
+                max_active = self._layer0_active(batch)
+            with self._mesh_scope():
+                with _span("tnn.resolve"):
+                    # resolution inside the mesh scope with the network's
+                    # column counts: the policy sees the mesh AND the
+                    # per-kernel Pallas capability, so the Pallas engines
+                    # survive when every layer's columns tile the mesh and
+                    # degrade only in the replication-fallback case;
+                    # Resolution.engine is what will actually run, so
+                    # stats/jit-variants record the truth.
+                    res = self._policy.resolve(
+                        self.scfg.backend, density=density,
+                        max_active=max_active,
+                        column_counts=self._column_counts,
+                        shape=self._bank_shape)
+                    engine = res.engine
+                    self._density_sum += density
+                    self._backend_steps[engine] = \
+                        self._backend_steps.get(engine, 0) + 1
+                    if res.predicted_us:
+                        want = min(res.predicted_us,
+                                   key=res.predicted_us.__getitem__)
+                        self._predicted_steps[want] = \
+                            self._predicted_steps.get(want, 0) + 1
+                        for name, us in res.predicted_us.items():
+                            self._predicted_us_sum[name] = \
+                                self._predicted_us_sum.get(name, 0.0) + us
+                    # sparse engines compile against a static compaction
+                    # width bucketed from this batch's own receptive-field
+                    # measurement
+                    width = res.width if engine in SPARSE_ENGINES else None
+                    learn = self._learn_gate()
+                with _span("tnn.h2d"):
+                    batch_dev = self._place(batch)
+                    carry_in = self._place_carry(carry_np)
+                with _span("tnn.dispatch"):
+                    if learn:
+                        # STDP step: outputs at the pre-update weights
+                        # (bit-exact with the inference path), new weights
+                        # advance the engine's explicit state — no
+                        # recompile, and under a mesh the update stays
+                        # column-sharded (layer_step pins it via
+                        # specs.tnn_param_axes)
+                        out_dev, carry_dev, new_params = self._fwd_for(
+                            engine, width, learn=True
+                        )(self.params, batch_dev, carry_in, self._stdp_key())
+                        self.params = new_params
+                        self.n_stdp_updates += 1
+                    else:
+                        out_dev, carry_dev = self._fwd_for(engine, width)(
+                            self.params, batch_dev, carry_in
+                        )
+                with _span("tnn.fetch"):
+                    out = np.asarray(out_dev)
+                    carry_out = tuple(
+                        None if c is None else np.asarray(c)
+                        for c in carry_dev
+                    )
+            retired: List[TNNRequest] = []
+            with _span("tnn.retire"):
+                for idx, entry in live:
+                    req = entry.item
+                    req.backends.add(engine)
+                    # copy: out[idx] is a view that would pin the whole
+                    # (B, C, Q) batch array for the life of the request
+                    req.outputs.append(out[idx].copy())
+                    req.cursor += 1
+                    if self.stateful:
+                        # scatter this row's new carry back into the
+                        # slot's state
+                        entry.state = tuple(
+                            None if c is None else c[idx].copy()
+                            for c in carry_out
+                        )
+                    if req.done:
+                        done_entry = self.pool.retire(idx)
+                        # the final carry leaves the pool on the entry;
+                        # hand it to the request so the client can
+                        # continue the stream later
+                        req.final_state = done_entry.state
+                        # keep only the timestamps for the latency summary
+                        # — holding the request (volleys + outputs +
+                        # state) would grow without bound in a long-lived
+                        # service
+                        self._retired.append(
+                            dataclasses.replace(done_entry, item=None,
+                                                state=None)
+                        )
+                        retired.append(req)
+            self.n_steps += 1
+            self.n_volleys += len(live)
+            # persistent counter + snapshot cadence: step_id advances AFTER
+            # the step's retirements, so a snapshot at step s commits every
+            # stream retired at-or-before s (the serve_resilient commit
+            # rule); advancing first also keeps maybe_save from firing at
+            # step 0
+            self.step_id += 1
+            self._maybe_snapshot()
         dt = time.perf_counter() - t0
         self._last_step_s = dt
         self._run_s += dt

@@ -128,8 +128,12 @@ class CheckpointManager:
         self.async_save = async_save
         self._thread: Optional[threading.Thread] = None
 
+    def due(self, step: int) -> bool:
+        """Whether ``maybe_save`` saves at ``step``: every ``every`` steps."""
+        return step % self.every == 0
+
     def maybe_save(self, step: int, state: Any) -> bool:
-        if step % self.every:
+        if not self.due(step):
             return False
         self.wait()
         # snapshot to host numpy BEFORE handing to the thread: the training

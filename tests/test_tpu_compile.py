@@ -13,6 +13,8 @@ process may load libtpu at a time, and every test worker imports this
 file. Where it cannot be described (no libtpu), every test skips.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -101,3 +103,23 @@ def test_layer1_forward_compiles(one_chip):
     params = (_spec(one_chip, 256, Q, 64, dtype=jnp.float32),)
     fwd = jax.jit(lambda p, v: network.forward(p, v, net).out)
     _assert_mosaic(fwd.lower(params, _spec(one_chip, B, net.n_inputs)))
+
+
+def test_bank_kernel_keeps_its_trace_name(one_chip):
+    """The device trace names the layer kernel's custom call after the
+    ``pallas_call``'s ``name=`` (``rnl_fire_times_layer.N``, which the chip
+    benchmark's ``rnl_kernel_ms`` matches); the layer's op scopes only add
+    to its op name."""
+    l1 = layer.TNNLayer(n_columns=16, rf_size=64, n_neurons=Q,
+                        threshold=20, t_steps=T, dendrite="catwalk", k=2,
+                        backend="pallas")
+    net = network.make_network([l1])
+    params = (_spec(one_chip, 16, Q, 64, dtype=jnp.float32),)
+    fwd = jax.jit(lambda p, v: network.forward(p, v, net).out)
+    text = fwd.lower(params, _spec(one_chip, B, net.n_inputs)).compile() \
+        .as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        assert re.match(r"\s*(ROOT )?%rnl_fire_times_layer(\.\d+)? = ", line)
+        assert "/bank/" in line
