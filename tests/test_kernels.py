@@ -66,6 +66,82 @@ def test_rnl_agrees_with_core_neuron():
                                       np.asarray(want))
 
 
+@pytest.mark.parametrize("bsz", [1, 32, 130])
+@pytest.mark.parametrize("n,q,threshold", [(32, 12, 15), (12, 10, 4),
+                                           (256, 16, 40), (64, 200, 20),
+                                           (12, 10, 0)])
+@pytest.mark.parametrize("k", [None, 2])
+def test_layer_kernel_matches_engines(bsz, n, q, threshold, k):
+    """The lane-dense layer kernel == the jnp closed-form and scan engines,
+    fire times and clip counts, across its tiling edges: slots padded to
+    128 lanes (B=130 spans two lane tiles), 7 columns over column blocks
+    (n=256 and Q=200 pad them with silent columns), Q > 128 over neuron
+    tiles, threshold <= 0 (fires at tick 0 on silence)."""
+    _check_layer_kernel(*_random_layer(7, bsz, n, q), threshold, k)
+
+
+def test_layer_kernel_bounds_each_column_block():
+    """Each column block stops at its own last breakpoint: the first block's
+    ramps end by tick 2, the others' start at tick 8."""
+    from repro.kernels import rnl_neuron
+    assert rnl_neuron._layer_tiling(7, 256, 16, 2) == (3, 3)
+    times, w = _random_layer(7, 9, 256, 16)
+    times = times.at[:3].set(jnp.minimum(times[:3], 1))
+    w = w.at[:3].set(jnp.minimum(w[:3], 1))
+    times = times.at[3:].set(jnp.clip(times[3:], 8, 15))
+    _check_layer_kernel(times, w, 8, 2)
+
+
+def test_layer_kernel_reads_negative_weights_as_silent():
+    """A weight below 0 raises no ramp bit in the scan engine; the kernel's
+    ``#{times <= t} - #{times + w <= t}`` count clamps it to 0 to agree."""
+    from repro.core import neuron
+    from repro.kernels import rnl_neuron
+    times, w = _random_layer(3, 6, 32, 12)
+    w = w - 3
+    cfg = neuron.NeuronConfig(n_inputs=32, threshold=6, t_steps=16)
+    fire, clip = rnl_neuron.rnl_fire_times_layer(
+        times, w, t_steps=16, threshold=6, k=2, with_clip=True)
+    sim = neuron.simulate_neuron(
+        jnp.broadcast_to(times[:, :, None, :], (3, 6, 12, 32)),
+        jnp.broadcast_to(w[:, None, :, :], (3, 6, 12, 32)), cfg)
+    np.testing.assert_array_equal(np.asarray(fire),
+                                  np.asarray(sim.fire_time))
+    np.testing.assert_array_equal(np.asarray(clip),
+                                  np.asarray(sim.clip_events))
+
+
+def _random_layer(c, bsz, n, q):
+    """(C, B, n) spike times over T=16 ticks (30% silent lines, some past
+    the cycle) and (C, Q, n) 3-bit weights."""
+    from repro.core.coding import NO_SPIKE
+    kt, ks, kw = jax.random.split(jax.random.PRNGKey(bsz * n + q), 3)
+    times = jax.random.randint(kt, (c, bsz, n), 0, 18)
+    times = jnp.where(jax.random.uniform(ks, times.shape) < 0.3, NO_SPIKE,
+                      times)
+    return times, jax.random.randint(kw, (c, q, n), 0, 8)
+
+
+def _check_layer_kernel(times, w, threshold, k):
+    from repro.core import neuron
+    from repro.kernels import rnl_neuron
+    (c, bsz, n), q, t_steps = times.shape, w.shape[1], 16
+    cfg = neuron.NeuronConfig(
+        n_inputs=n, threshold=threshold, t_steps=t_steps,
+        dendrite="pc_compact" if k is None else "catwalk",
+        k=2 if k is None else k)
+    fire, clip = rnl_neuron.rnl_fire_times_layer(
+        times, w, t_steps=t_steps, threshold=threshold, k=k, with_clip=True)
+    for engine in ("closed_form", "scan"):
+        want = neuron.fire_times_bank(times, w, cfg, backend=engine)
+        np.testing.assert_array_equal(np.asarray(fire), np.asarray(want))
+    sim = neuron.simulate_neuron(
+        jnp.broadcast_to(times[:, :, None, :], (c, bsz, q, n)),
+        jnp.broadcast_to(w[:, None, :, :], (c, bsz, q, n)), cfg)
+    np.testing.assert_array_equal(np.asarray(clip),
+                                  np.asarray(sim.clip_events))
+
+
 # ------------------------------------------------------------------ ssd_scan
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("bh,L,p,n,chunk", [(2, 130, 16, 8, 64),

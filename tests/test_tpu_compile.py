@@ -6,7 +6,8 @@ the Pallas interpreter accepts on CPU — e.g. an output block whose last
 dim is neither lane-aligned nor the whole array. Shapes are the
 deployment widths ``chip_smoke.py`` serves: Q=16 neurons per column,
 n=64 (layer 1) and n=256 (layer 2) dendrites, compacted widths on the
-lane-aligned bucket ladder.
+lane-aligned bucket ladder; and the layer kernel at the chip benchmark's
+``tnn-proto-2l`` layers.
 
 The topology is described inside a fixture, never at import: only one
 process may load libtpu at a time, and every test worker imports this
@@ -77,12 +78,15 @@ def test_bank_kernel_compiles(one_chip, q, n):
         t_steps=T, threshold=20, k=2))
 
 
-@pytest.mark.parametrize("n_columns,n,with_clip", [
-    (256, 64, False), (16, 256, False), (16, 256 + Q, False),
-    (256, 64, True)])
-def test_layer_kernel_compiles(one_chip, n_columns, n, with_clip):
+@pytest.mark.parametrize("n_columns,n,q,bsz,with_clip", [
+    (256, 64, Q, B, False), (16, 256, Q, B, False),
+    (16, 256 + Q, Q, B, False), (256, 64, Q, B, True),
+    # the benchmark's tnn-proto-2l layers at its 128 and 32 slots
+    (625, 32, 12, 128, False), (625, 12, 10, 128, False),
+    (625, 32, 12, 32, True), (625, 12, 10, 32, False)])
+def test_layer_kernel_compiles(one_chip, n_columns, n, q, bsz, with_clip):
     _assert_mosaic(rnl_neuron.rnl_fire_times_layer.lower(
-        _spec(one_chip, n_columns, B, n), _spec(one_chip, n_columns, Q, n),
+        _spec(one_chip, n_columns, bsz, n), _spec(one_chip, n_columns, q, n),
         t_steps=T, threshold=20, k=2, with_clip=with_clip))
 
 
