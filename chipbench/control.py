@@ -4,13 +4,13 @@
         [--seconds 4] [--program]
 
 Runs the cell's traffic at its own size and load with the plain reference in
-the program's place, its weights held in bfloat16 (the nearest precision
-below the configuration's float32), and prints one JSON line per seed with
-every number the check compared and its limit; each line must read
-``correct: false``. With ``--program`` it runs the program instead, on the
-same seeds, for the readings of sound runs. All seeds run in one process, so
-set-up is paid once. Needs a TPU, like ``run.py``; the benchmark's own runs
-never run it.
+the program's place, one precision below the configuration's (the system's
+``control_engine``; for the TNN its weights in bfloat16, below float32), and
+prints one JSON line per seed with every number the check compared and its
+limit; each line must read ``correct: false``. With ``--program`` it runs the
+program instead, on the same seeds, for the readings of sound runs. All seeds
+run in one process, so set-up is paid once. Needs a TPU, like ``run.py``; the
+benchmark's own runs never run it.
 """
 
 import time
@@ -35,9 +35,8 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
-    import jax.numpy as jnp
 
-    from harness import runner, spec, system
+    from harness import runner, spec
 
     cell = spec.find_cell(spec.load_benchmark(), args.workload)
     if jax.devices()[0].platform != "tpu":
@@ -47,17 +46,17 @@ def main() -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     sys.path.insert(0, str(ROOT / "src"))
     if args.program:
-        engine = system.ProgramEngine
+        engine = cell.system.engine
     else:
-        def engine(weights, config, name):
-            return system.ControlEngine(weights, config, wdtype=jnp.bfloat16)
+        def engine(params, config, name):
+            return cell.system.control_engine(params, config)
     for seed in [int(s) for s in args.seeds.split(",")]:
         res = runner.run(cell, seed, args.seconds, False, time.perf_counter(),
                          engine=engine, log=functools.partial(
                              print, file=sys.stderr, flush=True))
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "engine": "program" if args.program else
-                          "control-bfloat16", "correct": res["correct"],
+                          "control", "correct": res["correct"],
                           "attempted": res["attempted"],
                           "readings": res["readings"],
                           "compared": res["compared"]}), flush=True)

@@ -10,7 +10,8 @@ request's result back at once. Every request keeps its own clock readings:
   started  start of the first step that served one of its volleys
   done     end of the step that retired it (its result is then in hand)
 
-Each step keeps (start, end, the program's count of volleys served). With
+A request's input is the system's ``payload(pool, offset, length)``. Each
+step keeps (start, end, the program's count of units served). With
 ``trace_dir`` set, the profiler records a slice of the window, and the
 benchmark's own calls (``submit``, ``step``, waiting for the next arrival)
 show in it as host spans named ``chipbench.<call>``.
@@ -44,21 +45,22 @@ class Request:
 
 
 class Window:
-    def __init__(self, engine, pool: np.ndarray, sched: traffic.Schedule, *,
-                 record_schedule: bool = False,
+    def __init__(self, engine, pool, sched: traffic.Schedule, *,
+                 payload: Callable, record_schedule: bool = False,
                  trace_dir: Optional[str] = None,
                  clock: Callable[[], float] = time.perf_counter):
         self.eng = engine
         self.pool = pool
+        self._payload = payload
         self.sched = sched
         self.clock = clock
         self.requests: List[Request] = []
-        #: (start, end, program's volleys served so far) per step
+        #: (start, end, program's units served so far) per step
         self.steps: List[Tuple[float, float, int]] = []
         #: this thread's CPU seconds in each step (a step far longer than
         #: its CPU time waited: on the device, on I/O, or descheduled)
         self.step_cpu: List[float] = []
-        #: per step, the (request idx, volley idx) pairs it served, in the
+        #: per step, the (request idx, unit idx) pairs it served, in the
         #: order the requests were sent (``record_schedule`` only)
         self.served: List[List[Tuple[int, int]]] = []
         self._record = record_schedule
@@ -105,9 +107,9 @@ class Window:
     def send(self, length: int, offset: int, due: float,
              warmup: bool = False) -> Request:
         r = Request(len(self.requests), int(length), int(offset), due, warmup)
-        volleys = traffic.stream(self.pool, r.offset, r.length)
+        payload = self._payload(self.pool, r.offset, r.length)
         with self._span("submit"):
-            h = self.eng.submit(volleys)
+            h = self.eng.submit(payload)
         r.sent = self.clock()
         self.requests.append(r)
         self._inflight[h.req_id] = (r, h, [0])

@@ -1,11 +1,13 @@
 """One run of one cell: set-up, the measured window, metrics, the check.
 
-Set-up (``setup_s``, from process start to the window's start): the volley
-pool from the seed in numpy, the weights from the seed in one jitted call on
-the device, the program's engine, and warm-up steps through that same engine
-with the cell's own shapes (a closed loop's first steps; for an open loop one
-full batch of two-volley requests, served to the end). Nothing compiles in
-the window after that.
+Everything that belongs to the system under test is reached through the
+cell's system module (``cell.system``, ``systems/__init__.py``).
+
+Set-up (``setup_s``, from process start to the window's start): the request
+pool from the seed, the parameters from the seed, the program's engine, and
+warm-up steps through that same engine with the cell's own shapes (a closed
+loop's first steps; for an open loop one full batch of two-unit requests,
+served to the end). Nothing compiles in the window after that.
 
 After the window: in-flight snapshots finish, the device's peak memory is
 read, the program's state is freed, and only then does the reference run.
@@ -26,7 +28,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from harness import check, loop, peaks, reference, spec, system, trace, traffic
+from harness import check, loop, peaks, spec, trace, traffic
 
 #: closed-loop steps before the window opens (the first one compiles)
 CLOSED_WARMUP_STEPS = 8
@@ -132,13 +134,6 @@ def _stalls(win: loop.Window, t0: float, gcp: _GcPauses) -> str:
         f"{1e3 * max(gcs, default=0.0):.1f} ms")
 
 
-def _stateful(config: Dict) -> bool:
-    """Whether outputs depend on what else was served: learning, or a
-    recurrent layer's carry (the check then replays the served order)."""
-    return bool(config.get("learn")) or any(
-        lay.get("recurrent") for lay in config["layers"])
-
-
 def _warmup(win: loop.Window, cell: spec.Cell, sched: traffic.Schedule
             ) -> None:
     if sched.due is None:
@@ -154,23 +149,25 @@ def _warmup(win: loop.Window, cell: spec.Cell, sched: traffic.Schedule
 
 
 def run(cell: spec.Cell, seed: int, seconds: float, with_trace: bool,
-        t_start: float, engine: Callable = system.ProgramEngine,
+        t_start: float, engine: Optional[Callable] = None,
         log=lambda msg: print(msg, file=sys.stderr, flush=True)) -> Dict:
-    """Run the cell once; returns the result line's object."""
-    cfg = cell.config
+    """Run the cell once; returns the result line's object. ``engine``
+    takes the place of the system's ``engine`` (the control runs)."""
+    cfg, sut = cell.config, cell.system
+    engine = engine or sut.engine
     marks = [("start", time.perf_counter())]
-    pool = traffic.volley_pool(cfg, seed)
+    pool = sut.pool(cfg, seed)
     marks.append(("pool", time.perf_counter()))
-    w0 = reference.init_weights(
+    w0 = sut.init_params(
         jax.random.PRNGKey(traffic.seed_key_int(seed, 3)), cfg)
-    w0_host = [np.asarray(w) for w in w0]
+    w0_host = jax.device_get(w0)
     marks.append(("weights", time.perf_counter()))
     eng = engine(w0, cfg, cell.name)
     marks.append(("engine", time.perf_counter()))
     sched = traffic.schedule(cell.traffic, seconds, seed)
     tmp = tempfile.TemporaryDirectory(prefix="chipbench-trace-")
-    win = loop.Window(eng, pool, sched,
-                      record_schedule=_stateful(cfg),
+    win = loop.Window(eng, pool, sched, payload=sut.payload,
+                      record_schedule=sut.stateful(cfg),
                       trace_dir=tmp.name if with_trace else None)
     _warmup(win, cell, sched)
     t0 = win.clock()
@@ -199,7 +196,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, with_trace: bool,
     log(_stalls(win, t0, gc_pauses))
     eng.close()
     memory_peak = _memory_peak()
-    w_final = eng.weights() if cfg.get("learn") else None
+    w_final = eng.final_state()
     n_snapshots = eng.n_snapshots
     win.eng = None          # free the program's state before the reference
     del eng, w0
@@ -223,8 +220,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, with_trace: bool,
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     t_check = time.perf_counter()
-    numbers = check.compare(cfg, pool, w0_host, win.requests, win.served,
-                            w_final)
+    numbers = sut.compare(cfg, pool, w0_host, win.requests, win.served,
+                          w_final)
     correct, compared = check.held(numbers, cfg["check"]["limits"])
     log(f"check {time.perf_counter() - t_check:.3f} s; numbers {numbers}; "
         f"steps {len(win.steps)}; snapshots {n_snapshots}")

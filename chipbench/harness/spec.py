@@ -1,16 +1,21 @@
 """Find a cell and everything it names, by name, from ``BENCHMARK.json``.
 
-A cell (an entry of ``workloads``) names a configuration and a traffic mix.
-Each of these, and each per-layer metric, lives in a file of its own:
+A cell (an entry of ``workloads``) names a configuration and a traffic mix;
+a configuration names the system it runs on (its ``"system"`` key, ``"tnn"``
+when it has none). Each of these, and each per-layer metric, lives in a file
+of its own:
 
   configs/<file named by the configuration entry>   the deployment as it is run
+  systems/<system>.py                                the system's pool, engine,
+                                                     reference and comparison
   traffic/<traffic>.json                             the mix's parameters
   metrics/<metric>.py                                a per-layer metric's reader
 
-so a later change adds a configuration, a mix or a metric by adding files and
-entries, never by editing a file that is already there. A metric named
-``<base>.<variant>`` (``step_ms.sat``) is read by ``metrics/<base>.<variant>.py``
-when that file exists, else by ``metrics/<base>.py``.
+so a later change adds a system, a configuration, a mix or a metric by adding
+files and entries, never by editing a file that is already there. A metric
+named ``<base>.<variant>`` (``step_ms.sat``) is read by
+``metrics/<base>.<variant>.py`` when that file exists, else by
+``metrics/<base>.py``.
 """
 
 from __future__ import annotations
@@ -19,11 +24,17 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
-from typing import Callable, Dict, List, Optional
+import sys
+import types
+from typing import Callable, Dict, List, Optional, Sequence
 
 #: the benchmark's own directory (``chipbench/``) and the checkout's root
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
+#: where ``find_cell`` looks for ``<system>.py``, in order
+SYSTEM_DIRS = (BENCH_DIR / "systems",)
+#: the system of a configuration that names none
+DEFAULT_SYSTEM = "tnn"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,6 +49,8 @@ class Cell:
     end_to_end: List[Dict]
     #: per-layer metric entries this cell reports (``--trace 1``)
     per_layer: List[Dict]
+    #: the configuration's system module (``systems/__init__.py``)
+    system: types.ModuleType
 
 
 def load_benchmark(path: Optional[pathlib.Path] = None) -> Dict:
@@ -56,8 +69,31 @@ def _reports(metric: Dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def find_cell(bench: Dict, name: str) -> Cell:
-    """The cell called ``name``, with its files loaded; KeyError if none."""
+def _load_module(path: pathlib.Path, prefix: str) -> types.ModuleType:
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    # a dataclass with string annotations looks its module up here
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_system(name: str, dirs: Sequence[pathlib.Path] = SYSTEM_DIRS
+                ) -> types.ModuleType:
+    """The module of the system called ``name``: the first ``<name>.py``
+    in ``dirs``; LookupError if none."""
+    for d in dirs:
+        path = pathlib.Path(d) / f"{name}.py"
+        if path.exists():
+            return _load_module(path, "chipbench_system_")
+    raise LookupError(f"no system {name!r} under {[str(d) for d in dirs]}")
+
+
+def find_cell(bench: Dict, name: str,
+              system_dirs: Sequence[pathlib.Path] = SYSTEM_DIRS) -> Cell:
+    """The cell called ``name``, with its files loaded and its system found
+    in ``system_dirs``; KeyError if there is no such cell."""
     entries = {w["name"]: w for w in bench["workloads"]}
     if name not in entries:
         raise KeyError(f"no workload {name!r}; have {sorted(entries)}")
@@ -73,7 +109,9 @@ def find_cell(bench: Dict, name: str) -> Cell:
                  if (_reports(m, name) if "workloads" in m
                      else m["moves"] in e2e_names)]
     return Cell(name=name, chips=int(w["chips"]), config=config,
-                traffic=traffic, end_to_end=e2e, per_layer=per_layer)
+                traffic=traffic, end_to_end=e2e, per_layer=per_layer,
+                system=load_system(config.get("system", DEFAULT_SYSTEM),
+                                   system_dirs))
 
 
 def reader_path(metric: str) -> pathlib.Path:
@@ -88,9 +126,4 @@ def reader_path(metric: str) -> pathlib.Path:
 
 def load_reader(metric: str) -> Callable:
     """The ``read(run)`` function of a per-layer metric's reader file."""
-    path = reader_path(metric)
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + path.stem.replace(".", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load_module(reader_path(metric), "chipbench_metric_").read

@@ -1,12 +1,13 @@
 """The system under test: the program's TNN serve engine, built from a
 configuration file. The only module of the benchmark that imports the program.
 
-``ProgramEngine`` exposes the four things the window drives and reads:
-``submit(volleys) -> handle``, ``step() -> retired handles``, ``has_work`` and
-``n_volleys`` (the program's count of volleys served). A handle has
-``req_id``, ``outputs`` (one (C, Q) array per served volley, growing as the
-stream is served) and ``result()``. ``ControlEngine`` offers the same
-interface over the reference, for the control runs.
+``ProgramEngine`` is the engine of ``systems/tnn.py``: ``submit(volleys) ->
+handle``, ``step() -> retired handles``, ``has_work``, ``n_volleys`` (the
+program's count of volleys served), ``close()``, ``n_snapshots`` and
+``final_state()`` (the learned weights, or None). A handle has ``req_id``,
+``outputs`` (one (C, Q) array per served volley, growing as the stream is
+served) and ``result()``. ``ControlEngine`` offers the same interface over
+the reference, for the control runs.
 """
 
 from __future__ import annotations
@@ -72,6 +73,7 @@ class ProgramEngine:
             checkpoint_every=int(config.get("checkpoint_every", 0)),
             checkpoint_keep=int(config.get("checkpoint_keep", 3)))
         self._ckpt = ckpt
+        self._learn = scfg.learn
         self._eng = tnn_engine.TNNEngine(params, _network(config), scfg)
 
     def submit(self, volleys: np.ndarray):
@@ -92,7 +94,10 @@ class ProgramEngine:
     def n_snapshots(self) -> int:
         return self._eng.n_snapshots
 
-    def weights(self) -> List[np.ndarray]:
+    def final_state(self) -> Optional[List[np.ndarray]]:
+        """The weights after serving, when the configuration learns."""
+        if not self._learn:
+            return None
         return [np.asarray(p) for p in self._eng.params]
 
     def close(self) -> None:
@@ -181,7 +186,9 @@ class ControlEngine:
         lay = self._spec.layers[0]
         return lay.stride * (lay.n_columns - 1) + lay.rf_size
 
-    def weights(self) -> List[np.ndarray]:
+    def final_state(self) -> Optional[List[np.ndarray]]:
+        if not self._spec.learn:
+            return None
         return [np.asarray(p) for p in self.params]
 
     def close(self) -> None:

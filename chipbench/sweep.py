@@ -51,7 +51,7 @@ def main() -> int:
     import jax
     import numpy as np
 
-    from harness import loop, reference, runner, spec, system, traffic
+    from harness import loop, runner, spec, traffic
 
     cell = spec.find_cell(spec.load_benchmark(), args.workload)
     loop_kind = "open" if args.rates else "closed"
@@ -62,16 +62,17 @@ def main() -> int:
     jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     sys.path.insert(0, str(ROOT / "src"))
-    cfg = cell.config
+    cfg, sut = cell.config, cell.system
+    pool = sut.pool(cfg, args.seed)
+    w0 = sut.init_params(
+        jax.random.PRNGKey(traffic.seed_key_int(args.seed, 3)), cfg)
     if args.slots:
-        pool = traffic.volley_pool(cfg, args.seed)
-        w0 = reference.init_weights(
-            jax.random.PRNGKey(traffic.seed_key_int(args.seed, 3)), cfg)
         for slots in [int(s) for s in args.slots.split(",")]:
             cfg_s = dict(cfg, n_slots=slots)
             tr = dict(cell.traffic, clients=2 * slots)
-            eng = system.ProgramEngine(w0, cfg_s, cell.name)
-            win = loop.Window(eng, pool, traffic.schedule(tr, 1.0, args.seed))
+            eng = sut.engine(w0, cfg_s, cell.name)
+            win = loop.Window(eng, pool, traffic.schedule(tr, 1.0, args.seed),
+                              payload=sut.payload)
             runner._warmup(win, dataclasses.replace(cell, config=cfg_s),
                            win.sched)
             n0, t0 = eng.n_volleys, win.clock()
@@ -84,16 +85,14 @@ def main() -> int:
                 flush=True)
             eng.close()
         return 0
-    pool = traffic.volley_pool(cfg, args.seed)
-    w0 = reference.init_weights(
-        jax.random.PRNGKey(traffic.seed_key_int(args.seed, 3)), cfg)
-    eng = system.ProgramEngine(w0, cfg, cell.name)
-    warm = loop.Window(eng, pool, traffic.schedule(cell.traffic, 1.0, 0))
+    eng = sut.engine(w0, cfg, cell.name)
+    warm = loop.Window(eng, pool, traffic.schedule(cell.traffic, 1.0, 0),
+                       payload=sut.payload)
     runner._warmup(warm, cell, warm.sched)
     for rate in [float(r) for r in args.rates.split(",")]:
         tr = dict(cell.traffic, rate_per_s=rate)
         sched = traffic.schedule(tr, args.seconds, args.seed)
-        win = loop.Window(eng, pool, sched)
+        win = loop.Window(eng, pool, sched, payload=sut.payload)
         t0 = win.clock()
         win.run_open(t0, t0 + args.seconds + 10.0)
         half = t0 + args.seconds / 2
