@@ -21,15 +21,31 @@ class MoEConfig:
     d_expert: int                 # expert FFN hidden dim
     n_shared: int = 0             # always-on shared experts (deepseek)
     dense_residual: bool = False  # parallel dense FFN branch (arctic)
-    #: 'catwalk' = sort/capacity top-k relocation (the paper's idea at
-    #: tensor granularity); 'dense' = worst-case all-expert einsum (the
-    #: "full parallel counter" baseline); 'catwalk_ep' = shard_map
-    #: expert-parallel relocation with explicit psum combine (§Perf).
-    dispatch: Literal["catwalk", "dense", "catwalk_ep"] = "catwalk"
-    capacity_factor: float = 1.25
+    #: renormalise the top-k gates to sum to 1 (deepseek-v2: no)
+    norm_topk: bool = True
+    #: (e_lo, e_held): the routed experts this chip holds, as one chip of
+    #: an expert-parallel deployment (models/moe.py); None holds them all
+    held: Optional[Tuple[int, int]] = None
+    #: 'catwalk' = drop-free sort relocation of the experts held here
+    #: (the paper's idea at tensor granularity); 'catwalk_ep' = the same
+    #: body under shard_map, expert-parallel, with a psum combine (§Perf)
+    dispatch: Literal["catwalk", "catwalk_ep"] = "catwalk"
     router_aux_loss: float = 0.01
     #: keep expert F dims FSDP-sharded at rest in the EP path (arctic)
     ep_fsdp: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071), as deepseek-v2 configures it:
+    rotary frequencies ramped between interpolation and extrapolation, and
+    the attention softmax scaled by mscale(factor, mscale_all_dim)^2."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +55,7 @@ class MLAConfig:
     d_nope: int = 128             # per-head non-rotary dim
     d_rope: int = 64              # shared rotary key dim
     d_v: int = 128                # per-head value dim
+    yarn: Optional[YarnConfig] = None   # None: plain rope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,6 +110,9 @@ class ModelConfig:
     rope_theta: float = 1e4
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    #: leading layers with a dense SwiGLU of width ``d_ff`` in place of the
+    #: MoE (deepseek-v2 ``first_k_dense_replace``); the rest are scanned
+    n_dense_layers: int = 0
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -143,6 +163,7 @@ class ModelConfig:
             else:
                 per_layer += d * hd * (self.n_heads + 2 * self.n_kv_heads)
                 per_layer += self.n_heads * hd * d
+        mixer = per_layer
         if self.moe is not None:
             e = self.moe
             per_layer += d * e.n_experts                                # router
@@ -153,7 +174,9 @@ class ModelConfig:
         elif self.d_ff and self.family not in ("ssm", "hybrid"):
             per_layer += 3 * d * self.d_ff                              # SwiGLU
             # (hybrid: the shared block's MLP is counted once, below)
-        total = emb + self.n_layers * per_layer
+        nd = self.n_dense_layers
+        total = emb + (self.n_layers - nd) * per_layer \
+            + nd * (mixer + 3 * d * self.d_ff)
         if self.hybrid is not None:
             # one shared attention+MLP block (params used every period)
             shared = d * hd * (self.n_heads + 2 * self.n_kv_heads) \
@@ -175,8 +198,8 @@ class ModelConfig:
         if self.moe is None:
             return self.param_count()
         e = self.moe
-        inactive = self.n_layers * (e.n_experts - e.top_k) * 3 \
-            * self.d_model * e.d_expert
+        inactive = (self.n_layers - self.n_dense_layers) \
+            * (e.n_experts - e.top_k) * 3 * self.d_model * e.d_expert
         return self.param_count() - inactive
 
     def smoke(self) -> "ModelConfig":
@@ -193,14 +216,12 @@ class ModelConfig:
             head_dim=16,
         )
         if self.moe is not None:
-            # capacity_factor 8: smoke scale is tiny, so make relocation
-            # drop-free — decode==forward equivalence tests rely on it
             repl["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=min(2, self.moe.top_k),
-                d_expert=32, capacity_factor=8.0)
+                d_expert=32)
         if self.mla is not None:
-            repl["mla"] = MLAConfig(kv_lora_rank=32, d_nope=16, d_rope=8,
-                                    d_v=16)
+            repl["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32, d_nope=16, d_rope=8, d_v=16)
         if self.ssm is not None:
             repl["ssm"] = dataclasses.replace(self.ssm, d_state=16,
                                               head_dim=16, chunk=32)

@@ -71,7 +71,7 @@ def apply_opt(cfg: ModelConfig) -> ModelConfig:
     if cfg.moe is not None:
         # H5: shard_map expert-parallel relocation dispatch
         repl["moe"] = dataclasses.replace(
-            cfg.moe, capacity_factor=1.0, dispatch="catwalk_ep",
+            cfg.moe, dispatch="catwalk_ep",
             ep_fsdp=cfg.param_count() > 100e9)
     return dataclasses.replace(cfg, **repl)
 

@@ -9,6 +9,8 @@ f32 and casts back.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,17 +50,65 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D) with D even; positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1 * mscale * ln(factor) + 1
+    (1 for factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """(D/2,) YaRN rotary frequencies (``configs.base.YarnConfig``): the
+    plain frequencies where a dimension turns more than ``beta_fast`` times
+    over the original context, divided by ``factor`` where it turns fewer
+    than ``beta_slow`` times, and a linear ramp between (deepseek-v2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    def turns_dim(turns: float) -> float:
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = rope_freqs(head_dim, theta)
+    keep = 1.0 - ramp                     # 1: extrapolate (plain frequency)
+    return (extra / yarn.factor * ramp + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs=None, scale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, D) with D even; positions: broadcastable to (..., S).
+    Rotates the interleaved pairs (x[2i], x[2i+1]) by position * freqs[i]
+    (``rope_freqs`` unless ``freqs`` is given); cos and sin are multiplied
+    by ``scale`` (YaRN's cos/sin mscale)."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta))                  # (D/2,)
+    freqs = jnp.asarray(rope_freqs(d, theta) if freqs is None else freqs)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, D/2)
-    cos = jnp.cos(angles)[..., None, :]                        # (..., S, 1, D/2)
-    sin = jnp.sin(angles)[..., None, :]
+    cos = scale * jnp.cos(angles)[..., None, :]                # (..., S, 1, D/2)
+    sin = scale * jnp.sin(angles)[..., None, :]
     x1 = x[..., 0::2].astype(jnp.float32)
     x2 = x[..., 1::2].astype(jnp.float32)
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def einsum_f32(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """``jnp.einsum`` accumulating in float32 whatever the operands' float
+    dtype: bf16 operands stay bf16 on the TPU's matrix unit. XLA's CPU dot
+    has no bf16 x bf16 -> f32 form, so when lowered for the CPU the
+    operands are widened first (the same products, exactly)."""
+    f32 = jnp.float32
+
+    def widened(a, b):
+        return jnp.einsum(spec, a.astype(f32), b.astype(f32))
+
+    def native(a, b):
+        return jnp.einsum(spec, a, b, preferred_element_type=f32)
+
+    return jax.lax.platform_dependent(a, b, cpu=widened, default=native)
 
 
 # --------------------------------------------------------------- gated MLP

@@ -34,8 +34,9 @@ def _dt(cfg: ModelConfig):
 
 
 # ============================================================ init =======
-def _block_init(key, cfg: ModelConfig, dtype):
-    """One decoder block (attention + FFN/MoE + norms)."""
+def _block_init(key, cfg: ModelConfig, dtype, dense: bool = False):
+    """One decoder block (attention + FFN/MoE + norms); ``dense``: a plain
+    SwiGLU of width ``d_ff`` in place of the MoE (the leading layers)."""
     k1, k2 = jax.random.split(key)
     p = {"norm1": L.rmsnorm_init(cfg.d_model),
          "norm2": L.rmsnorm_init(cfg.d_model)}
@@ -43,7 +44,7 @@ def _block_init(key, cfg: ModelConfig, dtype):
         p["attn"] = A.mla_init(k1, cfg, dtype)
     else:
         p["attn"] = A.gqa_init(k1, cfg, dtype)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["moe"] = M.moe_init(k2, cfg.d_model, cfg.moe, dtype)
         if cfg.moe.dense_residual:
             p["mlp"] = L.mlp_init(jax.random.fold_in(k2, 7), cfg.d_model,
@@ -100,7 +101,12 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
         params["frame_proj"] = L.dense_init(keys[4], cfg.frontend.d_embed,
                                             cfg.d_model, dtype)
     else:
-        params["layers"] = stack(_block_init, cfg.n_layers, keys[2])
+        nd = cfg.n_dense_layers
+        params["layers"] = stack(_block_init, cfg.n_layers - nd, keys[2])
+        if nd:
+            params["dense_layers"] = stack(
+                lambda k, c, d: _block_init(k, c, d, dense=True), nd,
+                keys[5])
         if cfg.family == "vlm":
             params["patch_proj"] = L.dense_init(
                 keys[4], cfg.frontend.d_embed, cfg.d_model, dtype)
@@ -108,6 +114,21 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
 
 
 # ======================================================== forward ========
+def _ffn_apply(p, h, cfg: ModelConfig):
+    """The block's FFN: MoE (+ dense residual) or a dense SwiGLU.
+    Returns (out, aux_loss)."""
+    if "moe" not in p:
+        return L.mlp_apply(p["mlp"], h), jnp.zeros((), jnp.float32)
+    if cfg.moe.dispatch == "catwalk_ep":
+        out, stats = M.moe_apply_ep(p["moe"], h, cfg.moe,
+                                    fsdp=cfg.moe.ep_fsdp)
+    else:
+        out, stats = M.moe_apply(p["moe"], h, cfg.moe)
+    if cfg.moe.dense_residual:
+        out = out + L.mlp_apply(p["mlp"], h)
+    return out, stats["aux_loss"]
+
+
 def _block_apply(p, x, cfg: ModelConfig, cache=None, enc_out=None):
     """Returns (x, aux_loss, new_cache)."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
@@ -122,18 +143,7 @@ def _block_apply(p, x, cfg: ModelConfig, cache=None, enc_out=None):
                            causal=False)
         x = x + a
     h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.moe is not None:
-        if cfg.moe.dispatch == "catwalk_ep":
-            out, stats = M.moe_apply_ep(p["moe"], h, cfg.moe,
-                                        fsdp=cfg.moe.ep_fsdp)
-        else:
-            out, stats = M.moe_apply(p["moe"], h, cfg.moe)
-        aux = stats["aux_loss"]
-        if cfg.moe.dense_residual:
-            out = out + L.mlp_apply(p["mlp"], h)
-    else:
-        out = L.mlp_apply(p["mlp"], h)
+    out, aux = _ffn_apply(p, h, cfg)
     return x + out, aux, new_cache
 
 
@@ -211,6 +221,9 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array, *,
             h, aux, _ = _block_apply(lp, h, cfg, enc_out=enc_out)
             return _act_constrain(h), aux
 
+        if "dense_layers" in params:
+            x, _ = jax.lax.scan(_maybe_remat(body, cfg), x,
+                                params["dense_layers"])
         x, auxs = jax.lax.scan(_maybe_remat(body, cfg), x, params["layers"])
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
@@ -228,7 +241,7 @@ class ServeState(NamedTuple):
     layer_caches: Any          # stacked per-layer caches (leading axis L)
     shared_cache: Any          # hybrid shared block cache (or None)
     enc_out: Any               # enc-dec encoder output (or None)
-    pos: jax.Array             # () int32
+    pos: jax.Array             # () int32, or (B,) per slot
 
 
 def init_serve_state(params, cfg: ModelConfig, batch: int, max_len: int, *,
@@ -276,11 +289,11 @@ def per_slot_state(state: ServeState, batch: int) -> ServeState:
     Replaces every scalar position with its ``(B,)`` vector layout
     (``attention.KVCache.pos``) so each batch row advances independently —
     the state layout continuous batching decodes against
-    (``repro.serve.engine.Engine.serve``): a freed row's position is reset
-    to 0 and the row re-fills with a new request while the other rows keep
-    decoding. ``decode_step`` is layout-agnostic (the cache ops branch on
-    ``pos.ndim``), so the same compiled step serves both layouts — one
-    retrace, no new code path.
+    (``repro.serve.engine.Engine``): a freed row is re-filled by
+    ``prefill_chunk``, which sets its position, while the other rows keep
+    decoding; entries above a row's position are never attended, so none
+    is cleared. ``decode_step`` is layout-agnostic (the cache ops branch on
+    ``pos.ndim``), so the same compiled step serves both layouts.
 
     Only attention-family caches position independent rows this way; SSM
     recurrences and the hybrid shared block carry no positional cache
@@ -304,28 +317,34 @@ def per_slot_state(state: ServeState, batch: int) -> ServeState:
         jnp.zeros((batch,), jnp.int32))
 
 
-def reset_slots(state: ServeState, free: jax.Array) -> ServeState:
-    """Zero the cache positions of the rows selected by ``free`` (B,) bool.
-
-    The admission reset for continuous batching: a re-filled slot starts
-    writing at position 0 again. Stale K/V content above the reset
-    position needs no clearing — the validity mask derived from ``pos``
-    (``attention._cache_valid``) already hides it. Requires a per-slot
-    state (:func:`per_slot_state`).
-    """
-    caches = state.layer_caches
-    if caches.pos.ndim != 2:
-        raise ValueError("reset_slots needs a per-slot state "
-                         "(see per_slot_state)")
-    return ServeState(
-        caches._replace(pos=jnp.where(free[None, :], 0, caches.pos)),
-        state.shared_cache, state.enc_out,
-        jnp.where(free, 0, state.pos))
-
-
 def decode_step(params, cfg: ModelConfig, state: ServeState,
                 tokens: jax.Array) -> Tuple[jax.Array, ServeState]:
-    """One decode step. tokens (B, 1) -> logits (B, V), new state."""
+    """One decode step. tokens (B, 1) -> logits (B, V), new state. MLA
+    models decode in the absorbed form (``_decode_mla``)."""
+    return decode_rows(params, cfg, state, tokens)
+
+
+def decode_rows(params, cfg: ModelConfig, state: ServeState,
+                tokens: jax.Array, active: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, ServeState]:
+    """``decode_step`` over some rows: ``active`` (B,) bool, per-slot
+    states only, the rows that decode; the others neither write their
+    cache nor advance (a slot mid-prefill, or free)."""
+    if cfg.mla is not None:
+        return _decode_mla(params, cfg, state, tokens, active)
+    if active is None:
+        return _decode_static(params, cfg, state, tokens)
+    caches = state.layer_caches
+    s_max = caches.k.shape[2]
+    parked = caches._replace(pos=jnp.where(active[None], caches.pos, s_max))
+    logits, new = _decode_static(
+        params, cfg, state._replace(layer_caches=parked), tokens)
+    return logits, _advance(new, state.pos, active)
+
+
+def _decode_static(params, cfg: ModelConfig, state: ServeState, tokens
+                   ) -> Tuple[jax.Array, ServeState]:
+    """Every row advances one token (scalar or per-slot positions)."""
     x = L.embed_lookup(params["embed"], tokens)
     pos = state.pos
 
@@ -392,6 +411,106 @@ def decode_step(params, cfg: ModelConfig, state: ServeState,
         new_state = ServeState(new_caches, state.shared_cache, state.enc_out,
                                pos + 1)
 
+    return _head(params, cfg, x[:, 0]), new_state
+
+
+def _head(params, cfg: ModelConfig, x):
+    """Final norm and output head: float32 logits (..., V)."""
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    return (x[:, 0] @ head), new_state
+    return L.einsum_f32("...d,dv->...v", x, head)
+
+
+def _advance(state: ServeState, pos, active) -> ServeState:
+    """Per-slot positions ``pos`` advanced by one in the ``active`` rows."""
+    new_pos = pos + active.astype(pos.dtype)
+    caches = state.layer_caches
+    return state._replace(
+        pos=new_pos, layer_caches=caches._replace(
+            pos=jnp.broadcast_to(new_pos, caches.pos.shape)))
+
+
+def _decode_mla(params, cfg: ModelConfig, state: ServeState, tokens,
+                active) -> Tuple[jax.Array, ServeState]:
+    """Absorbed-MLA decode over the stacked latent caches (L, B, S, R) and
+    (L, B, S, d_rope), which ride the layer loop as carried state: each
+    layer writes its token's entry at the row's position in place and
+    attends over the latent (``attention.mla_attend_absorbed``). The dense
+    leading layers run first, then the scanned MoE layers."""
+    caches = state.layer_caches
+    b, s_max = tokens.shape[0], caches.k.shape[2]
+    pos = jnp.broadcast_to(state.pos, (b,))
+    live = jnp.ones((b,), bool) if active is None else active
+    wpos = jnp.where(live, pos, s_max)               # inactive: dropped
+    q_pos = pos[:, None]
+    rows = jnp.arange(b)
+
+    def layer(carry, lp, i):
+        h, ckv, kr = carry
+        hn = L.rmsnorm(lp["norm1"], h, cfg.norm_eps)
+        q_nope, q_rope, c, r = A.mla_project(lp["attn"], hn, cfg, q_pos)
+        ckv = ckv.at[i, rows, wpos].set(c[:, 0], mode="drop")
+        kr = kr.at[i, rows, wpos].set(r[:, 0], mode="drop")
+        h = h + A.mla_attend_absorbed(lp["attn"], q_nope, q_rope, ckv[i],
+                                      kr[i], q_pos, cfg)
+        out, _ = _ffn_apply(lp, L.rmsnorm(lp["norm2"], h, cfg.norm_eps), cfg)
+        return h + out, ckv, kr
+
+    carry = (L.embed_lookup(params["embed"], tokens), caches.k, caches.v)
+    nd = cfg.n_dense_layers
+    for i in range(nd):
+        carry = layer(carry, jax.tree.map(lambda a: a[i],
+                                          params["dense_layers"]), i)
+    carry, _ = jax.lax.scan(
+        lambda c, xs: (layer(c, xs[0], xs[1]), None), carry,
+        (params["layers"], nd + jnp.arange(cfg.n_layers - nd)))
+    h, ckv, kr = carry
+    new = state._replace(layer_caches=A.KVCache(ckv, kr, caches.pos))
+    if state.pos.ndim == 0:
+        new = new._replace(pos=state.pos + 1, layer_caches=new.layer_caches
+                           ._replace(pos=caches.pos + 1))
+    else:
+        new = _advance(new, state.pos, live)
+    return _head(params, cfg, h[:, 0]), new
+
+
+def prefill_chunk(params, cfg: ModelConfig, state: ServeState,
+                  tokens: jax.Array, slot, start, n
+                  ) -> Tuple[jax.Array, ServeState]:
+    """Prefill one run of a slot's prompt: ``tokens`` (C,) at positions
+    ``start, start + 1, ...``, of which the first ``n`` are real (the rest
+    pad the chunk to its compiled length). Writes the slot's cache entries
+    for them, attending over the slot's earlier entries (the chunks before
+    it), sets the slot's position to ``start + n``, and returns the logits
+    (V,) at position ``start + n - 1`` and the new state. Only the slot's row of the cache is read and written:
+    it is taken out of the stacked caches, run through the full-sequence
+    blocks (MLA expanded per head), and put back. Per-slot states only."""
+    caches = state.layer_caches
+    nd = cfg.n_dense_layers
+    take = lambda a: jax.lax.dynamic_index_in_dim(a, slot, 1, keepdims=True)
+    row = A.KVCache(take(caches.k), take(caches.v),
+                   jnp.full((cfg.n_layers,), start, jnp.int32))
+
+    def body(h, xs):
+        lp, cache = xs
+        h, _, new_cache = _block_apply(lp, h, cfg, cache=cache)
+        return h, new_cache
+
+    h = L.embed_lookup(params["embed"], tokens[None])
+    parts = []
+    if nd:
+        h, part = jax.lax.scan(body, h, (
+            params["dense_layers"], jax.tree.map(lambda a: a[:nd], row)))
+        parts.append(part)
+    h, part = jax.lax.scan(body, h, (
+        params["layers"], jax.tree.map(lambda a: a[nd:], row)))
+    parts.append(part)
+    new_row = jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+    put = lambda a, r: jax.lax.dynamic_update_index_in_dim(a, r, slot, 1)
+    new_pos = state.pos.at[slot].set(start + n)
+    new = state._replace(
+        pos=new_pos, layer_caches=A.KVCache(
+            put(caches.k, new_row.k), put(caches.v, new_row.v),
+            jnp.broadcast_to(new_pos, caches.pos.shape)))
+    last = jax.lax.dynamic_index_in_dim(h[0], n - 1, 0, keepdims=False)
+    return _head(params, cfg, last), new

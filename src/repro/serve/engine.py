@@ -1,28 +1,35 @@
-"""Batched LM serving engine: continuous batching over per-slot decode state.
+"""Batched LM serving engine: continuous batching with chunked prefill.
 
-Decode runs over a fixed pool of B slots — the batch rows of one compiled
-``decode_step``. Each slot owns its request's full decode state: a cursor
-into the prompt, the last sampled token (both in the slot's
-:class:`~repro.serve.slots.SlotEntry` ``state``), and — the piece that makes
-re-fill possible — its OWN write position into the KV cache
+Requests come in through ``submit`` and are served by ``step``, one call at
+a time, over a fixed pool of B slots: the batch rows of one compiled decode
+step. Each slot owns its request's cache rows and its own position in them
 (``attention.KVCache`` with vector ``pos``; ``transformer.per_slot_state``).
-A request is admitted into a free slot, prefilled token-by-token through the
-same cached decode path the dry-run lowers (bit-identical to serve_step),
-decodes until EOS or budget, and retires per-slot; the freed row's cache
-position resets to zero (``transformer.reset_slots`` — stale K/V above the
-reset is hidden by the validity mask, no clearing needed) and the row
-re-fills from the pending queue at the top of the next step, mid-flight,
-while the other rows keep decoding. Because attention rows are independent,
-a request's sampled tokens are identical whatever the batch composition —
-continuous batching changes throughput, never outputs (greedy; pinned by
-tests/test_serve_lm.py). On a TPU this holds between slot counts of 2 or
-more only: a one-row decode matmul rounds differently (DESIGN.md §5.2).
+Every ``step()``:
 
-Greedy and temperature sampling. Families whose decode state is not a
-positional KV cache (ssm / hybrid recurrences, audio's per-request encoder
-output) are served by the static wave path (``continuous=False`` semantics:
-admission only into an idle pool); everything attention-shaped gets
-continuous batching.
+  1. admits pending requests into free slots (FIFO, lowest free slot);
+  2. prefills at most ``prefill_tokens_per_step`` prompt tokens, in chunks
+     of the admitted requests in the order they were admitted: each chunk is
+     one launch of ``transformer.prefill_chunk``, which writes the slot's
+     cache rows for the chunk while attending over the chunks before it;
+  3. runs one decode step over all B rows for every slot whose prompt is in
+     its cache (absorbed latent attention for MLA models), the other rows
+     masked so they neither write nor advance;
+  4. fetches the step's new tokens, each with its logit and its row's
+     log-sum-exp, in one transfer, and retires requests that are done.
+
+Decoding is never starved by a queue of long prompts: prefill takes a fixed
+share of each step (chunked prefill, as in Sarathi-Serve). The last sampled
+token of every slot stays on the device between launches, so a step waits
+on the device once, at its fetch. Because attention rows are independent
+and the expert layer drops nothing, a request's tokens do not depend on
+what else is served: continuous batching changes throughput, never outputs
+(greedy; pinned by tests/test_serve_lm.py). On a TPU this holds between
+slot counts of 2 or more only: a one-row decode matmul rounds differently
+(DESIGN.md §5.2).
+
+``serve`` and ``generate`` are loops over ``submit``/``step``. Families
+whose decode state is not a positional KV cache (ssm / hybrid recurrences,
+audio's per-request encoder output) are served by the static lockstep path.
 """
 
 from __future__ import annotations
@@ -45,17 +52,52 @@ class ServeConfig:
     max_len: int = 256
     temperature: float = 0.0        # 0 = greedy
     eos_id: int = tok.EOS
+    #: keep generating past EOS (serving benchmarks fix output lengths)
+    ignore_eos: bool = False
     seed: int = 0
+    #: slots of the continuous batch (``serve`` may ask for another count)
+    n_slots: int = 8
+    #: prompt tokens prefilled per step, at most; also the chunk length
+    prefill_tokens_per_step: int = 512
 
 
 @dataclasses.dataclass
 class LMRequest:
-    """One prompt's bookkeeping through the slot pool."""
+    """One prompt: what ``Engine.submit`` takes, and the handle it returns.
 
-    req_id: int
+    ``tokens``, ``logits`` and ``lse`` grow by one per generated token: its
+    id, its logit and the log-sum-exp of its row."""
+
     prompt: np.ndarray              # (len,) int32 token ids
     max_new_tokens: int
+    req_id: int = -1
     tokens: List[int] = dataclasses.field(default_factory=list)
+    logits: List[float] = dataclasses.field(default_factory=list)
+    lse: List[float] = dataclasses.field(default_factory=list)
+
+    def result(self) -> np.ndarray:
+        """(n, 3) float32: id, logit, log-sum-exp of each generated token."""
+        return np.stack([self.tokens, self.logits, self.lse],
+                        axis=-1).astype(np.float32).reshape(-1, 3)
+
+
+@dataclasses.dataclass
+class _Slot:
+    fed: int = 0                    # prompt tokens in the cache
+    planned: int = 0                # tokens whose launch has been issued
+
+
+def _pick(logits: jax.Array, key, temperature: float) -> jax.Array:
+    """(..., V) logits -> (..., 3) float32: sampled id, its logit, the
+    row's log-sum-exp."""
+    lg = logits.astype(jnp.float32)
+    if temperature > 0:
+        idx = jax.random.categorical(key, lg / temperature, axis=-1)
+    else:
+        idx = jnp.argmax(lg, axis=-1)
+    logit = jnp.take_along_axis(lg, idx[..., None], axis=-1)[..., 0]
+    return jnp.stack([idx.astype(jnp.float32), logit,
+                      jax.nn.logsumexp(lg, axis=-1)], axis=-1)
 
 
 class Engine:
@@ -63,24 +105,166 @@ class Engine:
         self.params = params
         self.cfg = cfg
         self.scfg = scfg
-        self._step = jax.jit(
+        t = scfg.temperature
+
+        def decode(p, st, last, active, key):
+            logits, st = T.decode_rows(p, cfg, st, last[:, None], active)
+            out = _pick(logits, key, t)
+            return out, st, jnp.where(active, out[:, 0].astype(jnp.int32),
+                                      last)
+
+        def prefill(p, st, last, tokens, slot, start, n, key):
+            logits, st = T.prefill_chunk(p, cfg, st, tokens, slot, start, n)
+            out = _pick(logits, key, t)
+            return out, st, last.at[slot].set(out[0].astype(jnp.int32))
+
+        # the state (caches) and the last tokens are updated in place
+        self._step = jax.jit(decode, donate_argnums=(1, 2))
+        self._prefill = jax.jit(prefill, donate_argnums=(1, 2))
+        self._static_step = jax.jit(
             lambda p, st, t: T.decode_step(p, cfg, st, t))
-        self._reset = jax.jit(T.reset_slots)
-        # filler token for free slot rows: must be in-vocab — smoke configs
-        # cap vocab below tok.PAD, and an out-of-vocab lookup embeds as NaN
-        # (jnp.take fill), which a free row would write into its K/V cache.
-        # Stale NaN survives a slot reset (0 * NaN in the probs @ V
-        # contraction over masked positions), so the row poisons every
-        # request admitted after it. A finite filler contributes exactly 0.
+        # filler token for free slot rows and chunk padding: must be
+        # in-vocab — smoke configs cap vocab below tok.PAD, and an
+        # out-of-vocab lookup embeds as NaN (jnp.take fill), which would be
+        # written into the cache. Stale NaN survives a slot reset (0 * NaN
+        # in the probs @ V contraction over masked positions), so the row
+        # would poison every request admitted after it. A finite filler
+        # contributes exactly 0.
         self._fill = int(min(tok.PAD, cfg.vocab_size - 1))
-        # throughput accounting for the last serve()/generate() call
+        self._key = jax.random.PRNGKey(scfg.seed)
+        self._chunk = min(scfg.prefill_tokens_per_step, scfg.max_len)
+        self._next_id = 0
+        self._step_id = 0
+        self.pool: Optional[SlotPool[LMRequest, _Slot]] = None
+        # counters: engine steps, prompt tokens prefilled, tokens decoded
         self.n_steps = 0
+        self.n_prefill_tokens = 0
+        self.n_decode_tokens = 0
+        if self._per_slot_ok:
+            self._build(scfg.n_slots)
 
     @property
     def _per_slot_ok(self) -> bool:
         """Families whose decode state re-fills per slot (KV caches)."""
         return self.cfg.family not in ("ssm", "hybrid", "audio")
 
+    def _build(self, n_slots: int) -> None:
+        """Fresh slots: ``n_slots`` rows of cache, all free."""
+        if n_slots < 1:
+            raise ValueError(f"need at least one slot, got {n_slots}")
+        self._state = None          # free the old caches first
+        self._state = jax.jit(lambda p: T.per_slot_state(
+            T.init_serve_state(p, self.cfg, n_slots, self.scfg.max_len),
+            n_slots))(self.params)
+        self._last = jnp.full((n_slots,), self._fill, jnp.int32)
+        self.pool = SlotPool(n_slots, on_admit=self._on_admit)
+
+    @staticmethod
+    def _on_admit(idx: int, entry) -> None:
+        del idx
+        entry.state = _Slot()
+
+    # ------------------------------------------------------ front door
+    def submit(self, req: LMRequest) -> LMRequest:
+        """Queue a request; returns it as its handle (``req_id`` set)."""
+        req.prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+        if req.prompt.size == 0:
+            raise ValueError(f"empty prompt (request {self._next_id})")
+        if req.prompt.size >= self.scfg.max_len:
+            raise ValueError(f"prompt of {req.prompt.size} tokens does not "
+                             f"fit max_len {self.scfg.max_len}")
+        req.req_id = self._next_id
+        self._next_id += 1
+        self.pool.submit(req)
+        return req
+
+    @property
+    def has_work(self) -> bool:
+        return self.pool.has_work
+
+    def step(self) -> List[LMRequest]:
+        """Admit, prefill a chunk budget, decode once; returns the requests
+        that retired."""
+        self._step_id += 1
+        self.n_steps += 1
+        with jax.profiler.StepTraceAnnotation("lm.step",
+                                              step_num=self._step_id):
+            with jax.profiler.TraceAnnotation("lm.admit"):
+                self.pool.admit()
+                live = sorted(self.pool.live(), key=lambda e: e[1].seq)
+            key = (jax.random.fold_in(self._key, self._step_id)
+                   if self.scfg.temperature > 0 else self._key)
+            launched = []           # (slot, device outputs) in token order
+            budget = self.scfg.prefill_tokens_per_step
+            for idx, entry in live:
+                req, st = entry.item, entry.state
+                n = min(self._chunk, req.prompt.size - st.fed, budget)
+                if n <= 0:
+                    continue
+                chunk = np.full((self._chunk,), self._fill, np.int32)
+                chunk[:n] = req.prompt[st.fed:st.fed + n]
+                with jax.profiler.TraceAnnotation("lm.prefill"):
+                    out, self._state, self._last = self._prefill(
+                        self.params, self._state, self._last, chunk, idx,
+                        st.fed, n, key)
+                st.fed += n
+                budget -= n
+                self.n_prefill_tokens += n
+                if st.fed == req.prompt.size:      # its first token
+                    st.planned = 1
+                    launched.append((idx, out))
+                else:
+                    launched.append((None, out))
+            active = np.zeros((self.pool.n_slots,), bool)
+            for idx, entry in live:
+                req, st = entry.item, entry.state
+                # the token to feed sits at position fed + planned - 1
+                if (st.fed == req.prompt.size
+                        and st.planned < req.max_new_tokens
+                        and st.fed + st.planned - 1 < self.scfg.max_len):
+                    active[idx] = True
+                    st.planned += 1
+            if active.any():
+                with jax.profiler.TraceAnnotation("lm.decode"):
+                    out, self._state, self._last = self._step(
+                        self.params, self._state, self._last, active, key)
+                self.n_decode_tokens += int(active.sum())
+                launched.append((active, out))
+            with jax.profiler.TraceAnnotation("lm.fetch"):
+                got = jax.device_get([out for _, out in launched])
+            with jax.profiler.TraceAnnotation("lm.retire"):
+                return self._retire(launched, got)
+
+    def _retire(self, launched, got) -> List[LMRequest]:
+        done: Dict[int, LMRequest] = {}
+        slots = dict(self.pool.live())
+        for (which, _), out in zip(launched, got):
+            if which is None:
+                continue
+            rows = ([(which, out)] if isinstance(which, int)
+                    else [(i, out[i]) for i in np.flatnonzero(which)])
+            for idx, row in rows:
+                if idx in done:                    # retired by EOS
+                    continue
+                entry = slots[idx]
+                if self._take(entry.item, row, entry.state):
+                    done[idx] = entry.item
+        for idx in done:
+            self.pool.retire(idx)
+        return list(done.values())
+
+    def _take(self, req: LMRequest, row: np.ndarray, st: _Slot) -> bool:
+        """Record one generated token; True when the request is done."""
+        t_new = int(row[0])
+        req.tokens.append(t_new)
+        req.logits.append(float(row[1]))
+        req.lse.append(float(row[2]))
+        n = len(req.tokens)
+        return (n >= req.max_new_tokens
+                or st.fed + n - 1 >= self.scfg.max_len
+                or (t_new == self.scfg.eos_id and not self.scfg.ignore_eos))
+
+    # ------------------------------------------------------ batch APIs
     def generate(self, prompts: List[np.ndarray],
                  max_new_tokens: int = 32,
                  frames: Optional[np.ndarray] = None) -> List[np.ndarray]:
@@ -97,82 +281,31 @@ class Engine:
     def serve(self, prompts: List[np.ndarray], max_new_tokens: int = 32, *,
               n_slots: Optional[int] = None,
               continuous: bool = True) -> List[np.ndarray]:
-        """Slot-based decode over ``n_slots`` rows; results in order.
+        """Serve ``prompts`` through ``submit``/``step`` on ``n_slots`` rows;
+        results in order.
 
         ``continuous=True`` re-fills freed slots from the pending queue
         mid-flight (the top of every step); ``continuous=False`` is the
-        wave baseline — admission only when the pool has fully drained, so
-        a batch's slowest request gates the next wave. Sampled tokens are
-        identical either way under greedy decoding (per-row attention
-        independence); only throughput differs.
+        wave baseline — the next wave is submitted only when the pool has
+        fully drained, so a batch's slowest request gates the next wave.
+        Sampled tokens are identical either way under greedy decoding (per-
+        row attention independence); only throughput differs.
         """
-        scfg = self.scfg
         b = len(prompts) if n_slots is None else int(n_slots)
-        if b < 1:
-            raise ValueError(f"need at least one slot, got {b}")
-        state = T.per_slot_state(
-            T.init_serve_state(self.params, self.cfg, b, scfg.max_len), b)
-
-        def on_admit(idx: int, entry) -> None:
-            del idx
-            # cursor into the prompt + last sampled token: the slot's
-            # host-side decode state (the cache position lives in the
-            # ServeState's per-slot pos, reset at admission below)
-            entry.state = {"fed": 0, "last": int(tok.PAD)}
-
-        pool: SlotPool[LMRequest, Dict[str, int]] = SlotPool(
-            b, on_admit=on_admit)
-        reqs = [
-            LMRequest(req_id=i, prompt=np.asarray(p, np.int32).reshape(-1),
-                      max_new_tokens=max_new_tokens)
-            for i, p in enumerate(prompts)]
-        for r in reqs:
-            if r.prompt.size == 0:
-                raise ValueError(f"empty prompt (request {r.req_id})")
-            pool.submit(r)
-
-        key = jax.random.PRNGKey(scfg.seed)
+        if self.pool is None or self.pool.has_work:
+            raise RuntimeError("serve() needs an idle per-slot engine")
+        if b != self.pool.n_slots:
+            self._build(b)
+        reqs = [LMRequest(prompt=p, max_new_tokens=max_new_tokens)
+                for p in prompts]
         self.n_steps = 0
-        while pool.has_work:
-            if continuous or pool.n_live == 0:
-                admitted = pool.admit()
-                if admitted:
-                    free = np.zeros((b,), bool)
-                    for idx, _ in admitted:
-                        free[idx] = True
-                    # re-filled rows restart at cache position 0; stale
-                    # K/V above it is hidden by the pos-derived validity
-                    # mask (attention._cache_valid), so no clearing
-                    state = self._reset(state, jnp.asarray(free))
-            tokens = np.full((b, 1), self._fill, np.int32)
-            for idx, entry in pool.live():
-                req, st = entry.item, entry.state
-                tokens[idx, 0] = (req.prompt[st["fed"]]
-                                  if st["fed"] < len(req.prompt)
-                                  else st["last"])
-            logits, state = self._step(self.params, state, tokens)
-            self.n_steps += 1
-            lg = np.asarray(logits, np.float32)
-            if scfg.temperature > 0:
-                key, k2 = jax.random.split(key)
-                nxt = np.asarray(jax.random.categorical(
-                    k2, jnp.asarray(lg) / scfg.temperature, axis=-1))
-            else:
-                nxt = lg.argmax(-1)
-            for idx, entry in list(pool.live()):
-                req, st = entry.item, entry.state
-                st["fed"] += 1
-                if st["fed"] < len(req.prompt):
-                    continue            # mid-prefill: logits not sampled
-                # this step consumed the final prompt token (first
-                # generated token) or the previous sample (next one)
-                t_new = int(nxt[idx])
-                req.tokens.append(t_new)
-                st["last"] = t_new
-                if (t_new == scfg.eos_id
-                        or len(req.tokens) >= req.max_new_tokens
-                        or st["fed"] >= scfg.max_len):
-                    pool.retire(idx)
+        waves = [reqs] if continuous else [reqs[i:i + b]
+                                           for i in range(0, len(reqs), b)]
+        for wave in waves:
+            for r in wave:
+                self.submit(r)
+            while self.has_work:
+                self.step()
         return [np.asarray(r.tokens, np.int32) for r in reqs]
 
     def _generate_static(self, prompts: List[np.ndarray],
@@ -210,7 +343,8 @@ class Engine:
         logits = None
         self.n_steps = 0
         for t in range(max_prompt):
-            logits, state = self._step(self.params, state, mat[:, t:t + 1])
+            logits, state = self._static_step(self.params, state,
+                                              mat[:, t:t + 1])
             self.n_steps += 1
         # first generated token comes from the final prompt position
         for i in range(max_new_tokens):
@@ -227,7 +361,7 @@ class Engine:
                     pool.retire(r)
             if pool.n_live == 0:
                 break
-            logits, state = self._step(self.params, state,
-                                       nxt.astype(np.int32)[:, None])
+            logits, state = self._static_step(self.params, state,
+                                              nxt.astype(np.int32)[:, None])
             self.n_steps += 1
         return [np.array(o, np.int32) for o in outs]

@@ -115,7 +115,7 @@ def _param_spec_base(name: str, shape: Tuple[int, ...], mesh: Mesh,
     return P(*([None] * len(shape)))
 
 
-_STACK_KEYS = ("layers", "encoder")
+_STACK_KEYS = ("layers", "dense_layers", "encoder")
 
 
 def param_pspec(path, leaf, mesh: Mesh, replicate_embed: bool = False) -> P:

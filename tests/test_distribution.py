@@ -27,7 +27,7 @@ def _run(code: str) -> str:
 
 def test_moe_ep_dispatch_matches_pjit_dispatch():
     """shard_map expert-parallel dispatch == single-device catwalk dispatch
-    (same routing, drop-free capacity) on a (2, 4) mesh."""
+    (same routing, one drop-free body) on a (2, 4) mesh."""
     print(_run("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -37,7 +37,7 @@ def test_moe_ep_dispatch_matches_pjit_dispatch():
         from repro.sharding import compat
 
         cfg = get_config('deepseek-v2-lite-16b').smoke()
-        mcfg = dataclasses.replace(cfg.moe, capacity_factor=8.0)
+        mcfg = cfg.moe
         key = jax.random.PRNGKey(0)
         p = M.moe_init(key, cfg.d_model, mcfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),

@@ -81,9 +81,9 @@ def test_decode_batch_is_slot_count_and_alone_matches_batched(lm, n_slots):
     seen = []
     step = lm._step
 
-    def spy(p, st, t):
-        seen.append(t.shape)
-        return step(p, st, t)
+    def spy(p, st, last, active, key):
+        seen.append((last.shape, active.shape))
+        return step(p, st, last, active, key)
 
     prompts = _prompts((2, 5, 3), lm.cfg.vocab_size, seed=3)
     lm._step = spy
@@ -91,15 +91,15 @@ def test_decode_batch_is_slot_count_and_alone_matches_batched(lm, n_slots):
         batched = lm.serve(prompts, max_new_tokens=3, n_slots=n_slots)
     finally:
         lm._step = step
-    assert seen and set(seen) == {(n_slots, 1)}
+    assert seen and set(seen) == {((n_slots,), (n_slots,))}
     for i, p in enumerate(prompts):
         alone = lm.serve([p], max_new_tokens=3, n_slots=n_slots)[0]
         np.testing.assert_array_equal(alone, batched[i], err_msg=f"req {i}")
 
 
 def test_per_slot_state_shapes_and_reset():
-    """per_slot_state vectorises cache positions; reset_slots zeroes only
-    the freed rows."""
+    """per_slot_state vectorises cache positions; re-filling a slot
+    (prefill_chunk from position 0) resets only that row's position."""
     cfg = get_config("internlm2-1.8b").smoke()
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     state = T.per_slot_state(T.init_serve_state(params, cfg, 3, 16), 3)
@@ -109,7 +109,8 @@ def test_per_slot_state_shapes_and_reset():
         pos=state.pos + 5,
         layer_caches=state.layer_caches._replace(
             pos=state.layer_caches.pos + 5))
-    out = T.reset_slots(bumped, np.array([True, False, True]))
-    np.testing.assert_array_equal(np.asarray(out.pos), [0, 5, 0])
+    _, out = T.prefill_chunk(params, cfg, bumped,
+                             np.array([4, 5, 6, 7], np.int32), 1, 0, 3)
+    np.testing.assert_array_equal(np.asarray(out.pos), [5, 3, 5])
     np.testing.assert_array_equal(np.asarray(out.layer_caches.pos[0]),
-                                  [0, 5, 0])
+                                  [5, 3, 5])
