@@ -87,7 +87,8 @@ def _client_streams(rng, n_clients: int):
 def _engine_steps(stats: dict) -> dict:
     """The ``steps_<engine>`` rows of ``TNNEngine.stats()`` (what ran)."""
     return {k[len("steps_"):]: int(v) for k, v in stats.items()
-            if k.startswith("steps_") and not k.startswith("steps_predicted")}
+            if k.startswith("steps_") and k != "steps_measured"
+            and not k.startswith("steps_predicted")}
 
 
 def _serve(eng, streams):

@@ -103,11 +103,15 @@ def main():
     dens = " -> ".join(f"{d:.2f}" for d in per_layer)
     policy = ", ".join(f"{k[len('steps_'):]}:{int(v)}"
                        for k, v in sorted(st.items())
-                       if k.startswith("steps_"))
+                       if k.startswith("steps_") and k != "steps_measured")
+    # the batch density is measured only where the policy reads it (not
+    # on the TPU Pallas fast path)
+    batch_density = (f"batch density={st['density_mean']:.2f}  "
+                     if "density_mean" in st else "")
     print(f"layer input densities (req 0): {dens}")
     print(f"steps={int(st['n_steps'])}  "
           f"occupancy={st['slot_occupancy']:.2f}  "
-          f"batch density={st['density_mean']:.2f}  "
+          f"{batch_density}"
           f"backend steps: {policy}  "
           f"throughput={st.get('volleys_per_s', 0.0):.0f} volleys/s")
     print(f"latency ms: mean={st['latency_ms_mean']:.1f} "

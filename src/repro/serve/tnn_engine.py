@@ -41,6 +41,10 @@ widths few, and the per-(engine, width) variant cache is a bounded LRU
 (``TNNServeConfig.max_jit_variants``; evictions surface in ``stats()``).
 All engines are bit-exact, so the policy is invisible in the outputs;
 ``stats()`` reports the mean measured density and per-engine step counts.
+A step measures only when its resolution can use the result: on a TPU,
+where the fused Pallas kernel preempts ``auto``, and with an explicit
+non-sparse backend, the host skips the measurement
+(``stats()['steps_measured']`` counts the steps that took it).
 
 Empty slots carry all-``NO_SPIKE`` volleys: silent lines never fire a neuron,
 so padding rows are inert, and the batch shape stays static — one XLA
@@ -121,7 +125,8 @@ class TNNServeConfig:
     n_slots: int = 8
     #: fire_times_bank engine for every layer: scan | closed_form | event |
     #: pallas | auto. ``auto`` re-resolves every step from the *measured*
-    #: batch activity (host-side, before the jit boundary) through the
+    #: batch activity (host-side, before the jit boundary; not measured
+    #: where the TPU Pallas kernel preempts) through the
     #: configured ``policy`` — NO_SPIKE-padded slot batches are exactly
     #: the sparse case the event engine wins on. All engines are
     #: bit-exact, so the policy never changes outputs.
@@ -376,6 +381,9 @@ class TNNEngine:
         self.n_volleys = 0
         self._run_s = 0.0
         self._density_sum = 0.0
+        #: steps whose batch the host measured (``_measures``); the
+        #: density means average over these
+        self._steps_measured = 0
         self._backend_steps: Dict[str, int] = {}
         # predicted-vs-chosen accounting: what the cost predictor wanted
         # (pre mesh degradation) vs what ran, plus its runtime estimates
@@ -600,6 +608,7 @@ class TNNEngine:
         self.n_volleys = 0
         self._run_s = 0.0
         self._density_sum = 0.0
+        self._steps_measured = 0
         self._stage_density_sums = [0.0] * self.n_stages
         self._backend_steps = {}
         self._predicted_steps = {}
@@ -687,6 +696,16 @@ class TNNEngine:
             else (jnp.asarray(c) if sh is None else jax.device_put(c, sh))
             for c, sh in zip(carry_np, self._carry_shardings)
         )
+
+    def _measures(self) -> bool:
+        """Whether this step's resolution can use a measured batch: ``auto``
+        off the TPU Pallas fast path ranks engines by it, and an explicit
+        sparse engine takes its compaction width from it. Asked inside the
+        mesh scope, where the policy's Pallas capability check reads the
+        mesh the step runs under."""
+        return (self.scfg.backend in SPARSE_ENGINES
+                or self._policy.wants_density(self.scfg.backend,
+                                              self._column_counts))
 
     def _layer0_active(self, batch: np.ndarray) -> int:
         """Max active-line count over the batch's layer-0 receptive
@@ -789,24 +808,31 @@ class TNNEngine:
                         for c, s in zip(carry_np, entry.state):
                             if c is not None:
                                 c[idx] = s
-            with _span("tnn.measure"):
-                # measured batch density (host-side — the jit boundary
-                # can't see it): NO_SPIKE-padded free slots count as silent
-                # lines, which is precisely why partially-filled batches
-                # resolve to the event path. Under pipelining the same
-                # measurement lands per micro-batch, so stats() can show
-                # each stage's traffic; the step-level resolution stays
-                # whole-batch (one compiled schedule serves all stages).
-                density = float(np.mean(batch < self._t_steps))
-                if self.n_stages > 1:
-                    for i, (lo, hi) in enumerate(self._stage_rows):
-                        self._stage_density_sums[i] += float(
-                            np.mean(batch[lo:hi] < self._t_steps))
-                # the measured layer-0 active count feeds both the cost
-                # ranking and the compaction bucket (width stays
-                # exact-covering: no active line drops)
-                max_active = self._layer0_active(batch)
             with self._mesh_scope():
+                with _span("tnn.measure"):
+                    density = max_active = None
+                    if self._measures():
+                        # measured batch density (host-side — the jit
+                        # boundary can't see it): NO_SPIKE-padded free
+                        # slots count as silent lines, which is precisely
+                        # why partially-filled batches resolve to the
+                        # event path. Under pipelining the same
+                        # measurement lands per micro-batch, so stats()
+                        # can show each stage's traffic; the step-level
+                        # resolution stays whole-batch (one compiled
+                        # schedule serves all stages).
+                        density = float(np.mean(batch < self._t_steps))
+                        self._density_sum += density
+                        self._steps_measured += 1
+                        if self.n_stages > 1:
+                            for i, (lo, hi) in enumerate(self._stage_rows):
+                                self._stage_density_sums[i] += float(
+                                    np.mean(batch[lo:hi] < self._t_steps))
+                        # the measured layer-0 active count feeds both
+                        # the cost ranking and the compaction bucket
+                        # (width stays exact-covering: no active line
+                        # drops)
+                        max_active = self._layer0_active(batch)
                 with _span("tnn.resolve"):
                     # resolution inside the mesh scope with the network's
                     # column counts: the policy sees the mesh AND the
@@ -821,7 +847,6 @@ class TNNEngine:
                         column_counts=self._column_counts,
                         shape=self._bank_shape)
                     engine = res.engine
-                    self._density_sum += density
                     self._backend_steps[engine] = \
                         self._backend_steps.get(engine, 0) + 1
                     if res.predicted_us:
@@ -935,11 +960,16 @@ class TNNEngine:
         if self.n_steps > 0:
             denom = self.n_steps * self.scfg.n_slots
             out["slot_occupancy"] = self.n_volleys / denom
-            out["density_mean"] = self._density_sum / self.n_steps
+        # batch densities: means over the steps the host measured (none on
+        # the TPU Pallas fast path, which needs no measurement)
+        out["steps_measured"] = float(self._steps_measured)
+        if self._steps_measured > 0:
+            out["density_mean"] = self._density_sum / self._steps_measured
         out["pipeline_microbatches"] = float(self.n_stages)
-        if self.n_steps > 0 and self.n_stages > 1:
+        if self._steps_measured > 0 and self.n_stages > 1:
             for i, total in enumerate(self._stage_density_sums):
-                out[f"density_stage{i}_mean"] = total / self.n_steps
+                out[f"density_stage{i}_mean"] = \
+                    total / self._steps_measured
         for engine, steps in self._backend_steps.items():
             out[f"steps_{engine}"] = float(steps)
         # predicted-vs-chosen: which engine the cost predictor ranked
