@@ -34,8 +34,8 @@ def bench_one(params, net, streams, n_slots: int, backend: str) -> float:
     eng = tnn_engine.TNNEngine(
         params, net,
         tnn_engine.TNNServeConfig(n_slots=n_slots, backend=backend))
-    # warm the jit cache with the full workload: density-resolved backends
-    # ("auto", "event") compile per (engine, width-bucket) as slot
+    # warm the jit cache with the full workload: a sparse engine ("event")
+    # compiles per measured width bucket as slot
     # composition shifts, so a single-stream warmup would leave compiles
     # inside the timed region. Serving the identical population replays the
     # exact batch sequence, hitting every variant the timed run will use.

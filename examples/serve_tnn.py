@@ -9,9 +9,8 @@ the live slots (backend-dispatched ``fire_times_bank``).
 
 Verifies the engine's spike-time outputs are bit-exact against unbatched
 per-request ``TNNNetwork`` inference, then prints per-request measured
-spike density, the neuron-bank engine the ``auto`` density policy resolved
-each request's cycles to (sparse batches take the event engine's
-breakpoint solve — DESIGN.md §3.3), and throughput/latency stats.
+spike density, the neuron-bank engine the backend resolved to at
+construction (DESIGN.md §3.7), and throughput/latency stats.
 
 Run:  PYTHONPATH=src python examples/serve_tnn.py [--clients 64 --slots 8]
 """
@@ -92,27 +91,17 @@ def main():
         if not np.array_equal(ref, result):
             mismatches += 1
     st = eng.stats()
-    # show the sparse path engaging: measured per-request density and the
-    # engine the auto policy actually resolved each request's cycles to
+    # measured per-request density; every cycle ran eng.engine
     for req in reqs[:8]:
-        served = "+".join(sorted(req.backends))
         print(f"  req {req.req_id:3d}: {req.n_cycles} cycles, "
-              f"density {req.density:.2f} -> {served}")
+              f"density {req.density:.2f}")
     if len(reqs) > 8:
         print(f"  ... ({len(reqs) - 8} more requests)")
     dens = " -> ".join(f"{d:.2f}" for d in per_layer)
-    policy = ", ".join(f"{k[len('steps_'):]}:{int(v)}"
-                       for k, v in sorted(st.items())
-                       if k.startswith("steps_") and k != "steps_measured")
-    # the batch density is measured only where the policy reads it (not
-    # on the TPU Pallas fast path)
-    batch_density = (f"batch density={st['density_mean']:.2f}  "
-                     if "density_mean" in st else "")
     print(f"layer input densities (req 0): {dens}")
     print(f"steps={int(st['n_steps'])}  "
           f"occupancy={st['slot_occupancy']:.2f}  "
-          f"{batch_density}"
-          f"backend steps: {policy}  "
+          f"engine={eng.engine}  "
           f"throughput={st.get('volleys_per_s', 0.0):.0f} volleys/s")
     print(f"latency ms: mean={st['latency_ms_mean']:.1f} "
           f"p50={st['latency_ms_p50']:.1f} p95={st['latency_ms_p95']:.1f} "

@@ -7,8 +7,6 @@ statically (DESIGN.md §7.1):
 
   RPR001 private-jax          ``jax._src`` / ``jax.core.Tracer`` outside
                               ``sharding/compat.py``
-  RPR002 deprecated-forward   calls to the deprecated ``network_forward*``
-                              trio outside ``core/network.py``
   RPR003 host-leak-in-jit     host-side ``float()``/``int()``/``bool()``/
                               ``.item()``/``.tolist()``/``np.asarray`` or a
                               Python ``if``/``while`` on a value reachable
@@ -34,12 +32,6 @@ statically (DESIGN.md §7.1):
                               ``kernels/common.py`` (strict parsing lives
                               there; ``dict(os.environ)`` snapshots are
                               structurally allowed)
-  RPR009 deprecated-resolution  calls to the deprecated engine-resolution
-                              trio (``resolve_backend`` /
-                              ``effective_engine`` / ``pallas_shardable``)
-                              outside ``core/neuron.py`` /
-                              ``core/policy.py`` — use
-                              ``core.policy.EnginePolicy.resolve``
 
 Escape hatch: ``# repro-lint: allow[<slug>]`` on the flagged line or the
 line directly above silences that rule there; ``# repro-lint: unplaced``
@@ -69,7 +61,6 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 RULES: Dict[str, Tuple[str, str]] = {
     "private-jax": ("RPR001", "jax._src / jax.core.Tracer outside "
                               "sharding/compat.py"),
-    "deprecated-forward": ("RPR002", "deprecated network_forward* call"),
     "host-leak-in-jit": ("RPR003", "host-side op on a jit-traced value"),
     "pallas-lane": ("RPR004", "BlockSpec literal last dim not a multiple "
                               "of the 128 TPU lane"),
@@ -81,23 +72,13 @@ RULES: Dict[str, Tuple[str, str]] = {
                                 "maybe_wsc nor is marked unplaced"),
     "raw-env": ("RPR008", "raw os.environ access outside "
                           "kernels/common.py"),
-    "deprecated-resolution": ("RPR009", "deprecated engine-resolution "
-                                        "helper call"),
 }
 
 #: files exempt from a rule entirely (posix path suffix match)
 PATH_EXEMPT: Dict[str, Tuple[str, ...]] = {
     "private-jax": ("sharding/compat.py",),
-    "deprecated-forward": ("core/network.py",),
     "raw-env": ("kernels/common.py",),
-    "deprecated-resolution": ("core/neuron.py", "core/policy.py"),
 }
-
-_DEPRECATED_FORWARD = {"network_forward", "network_forward_pipelined",
-                       "network_forward_with_densities"}
-
-_DEPRECATED_RESOLUTION = {"resolve_backend", "effective_engine",
-                          "pallas_shardable"}
 
 #: RPR007 fires only on files with a ``core`` path component, for
 #: top-level functions whose params hit BOTH operand classes.
@@ -493,8 +474,6 @@ class _FileLint:
     # -- rules ------------------------------------------------------------
     def run(self) -> None:
         self._rule_private_jax()
-        self._rule_deprecated_forward()
-        self._rule_deprecated_resolution()
         self._rule_host_leak()
         self._rule_pallas()
         self._rule_raw_env()
@@ -524,24 +503,6 @@ class _FileLint:
                     self._flag("private-jax", node,
                                f"`{dotted}` access (route through "
                                "sharding/compat.py)")
-
-    def _rule_deprecated_forward(self) -> None:
-        for node in ast.walk(self.tree):
-            if isinstance(node, ast.Call):
-                name = _terminal_name(node.func)
-                if name in _DEPRECATED_FORWARD:
-                    self._flag("deprecated-forward", node,
-                               f"`{name}` is deprecated; use "
-                               "network.forward / network.step")
-
-    def _rule_deprecated_resolution(self) -> None:
-        for node in ast.walk(self.tree):
-            if isinstance(node, ast.Call):
-                name = _terminal_name(node.func)
-                if name in _DEPRECATED_RESOLUTION:
-                    self._flag("deprecated-resolution", node,
-                               f"`{name}` is deprecated; use "
-                               "core.policy.EnginePolicy.resolve")
 
     def _rule_host_leak(self) -> None:
         finder = _JitSiteFinder(self.tree)

@@ -53,9 +53,9 @@ def measured_density(times, t_steps: int | None = None):
 
     With ``t_steps`` given, "active" means contributing-within-the-cycle
     (``times < t_steps``); without it, simply non-``NO_SPIKE``. Returns a
-    Python float so host-side policy code (:mod:`repro.core.policy`, the
-    serve engine) can branch on it; under ``jit`` the value is unknowable,
-    hence ``None``.
+    Python float for host-side diagnostics
+    (:func:`repro.core.network.measured_densities`); under ``jit`` the
+    value is unknowable, hence ``None``.
     """
     if compat.is_tracer(times):
         return None
@@ -76,25 +76,6 @@ def max_active(times, t_steps: int):
     return int(jnp.max(jnp.sum(mask.astype(jnp.int32), axis=-1)))
 
 
-def active_stats(times, t_steps: int):
-    """``(density, max_active)`` from one activity mask, ``(None, None)``
-    under tracing.
-
-    The cost-driven policy (:mod:`repro.core.policy`) needs both: density
-    ranks engines, the per-volley max picks the compaction bucket. One
-    mask serves both so the host-side measurement stays a single pass.
-    """
-    if compat.is_tracer(times):
-        return None, None
-    times = jnp.asarray(times)
-    if times.size == 0:
-        return 0.0, 0
-    mask = active_mask(times, t_steps).astype(jnp.int32)
-    per_volley = jnp.sum(mask, axis=-1)
-    return (float(jnp.mean(mask.astype(jnp.float32))),
-            int(jnp.max(per_volley)))
-
-
 #: Vector-lane width the compacted-shape ladder aligns to at/above one
 #: lane (mirrors ``repro.kernels.common.LANE``; defined locally so core
 #: never imports the kernels package).
@@ -110,7 +91,7 @@ def bucket_width(s: int, quantum: int = 8, lane: int = LANE_WIDTH) -> int:
 
       * jit variants stay few — O(log lane) small shapes plus O(n / lane)
         large ones — when the measured width drifts between batches (the
-        serve engine's per-(engine, width) cache is keyed on this);
+        serve engine's per-width variants are keyed on this);
       * every bucket >= ``lane`` is lane-aligned, so the ``pallas_compact``
         tick sweep reads full vector registers with no ragged tail
         (DESIGN.md §6.4).

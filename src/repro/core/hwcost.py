@@ -330,7 +330,6 @@ def calibrate(k: int = 2) -> CostModel:
 @functools.lru_cache(maxsize=None)
 def calibrated(k: int = 2) -> CostModel:
     """Memoized :func:`calibrate` — the model is deterministic in ``k``,
-    and hot-path consumers (the engine policy's tables, per-step serve
-    resolution, the paper-table regression suite) must not re-fit it per
-    call. CostModel is frozen, so sharing the instance is safe."""
+    and its consumers (the paper-table regression suite) must not re-fit
+    it per call. CostModel is frozen, so sharing the instance is safe."""
     return calibrate(k)

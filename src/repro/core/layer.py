@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import coding, compaction, neuron, stdp
-from repro.core import policy as engine_policy
 from repro.sharding import compat
 from repro.sharding import specs as sharding_specs
 
@@ -83,13 +82,6 @@ class TNNLayer:
     stdp: stdp.STDPConfig = dataclasses.field(default_factory=stdp.STDPConfig)
     #: minibatch STDP reduction: "mean" (default) or "sum".
     stdp_reduction: str = "mean"
-    #: engine-selection policy for ``backend="auto"`` (DESIGN.md §3.7):
-    #: None = the memoized cost-driven default
-    #: (:func:`repro.core.policy.default_policy`);
-    #: :func:`repro.core.policy.density_policy` restores the legacy
-    #: threshold. EnginePolicy is frozen/hashable, so the layer config
-    #: stays a valid static jit key.
-    policy: Optional[engine_policy.EnginePolicy] = None
 
     @property
     def stride(self) -> int:
@@ -182,9 +174,7 @@ def layer_input_density(volleys: jax.Array, cfg: TNNLayer,
     receptive fields (host diagnostic; ``None`` under jit).
 
     Overlapping fields count shared lines once per column — this is the
-    density the neuron banks actually see, the quantity the ``auto``
-    engine policy ranks candidates at
-    (:meth:`repro.core.policy.EnginePolicy.resolve`).
+    density the neuron banks actually see.
     """
     if compat.is_tracer(volleys):
         return None
@@ -239,8 +229,7 @@ def layer_forward(weights: jax.Array, volleys: jax.Array, cfg: TNNLayer,
     with jax.named_scope("bank"):
         fire = neuron.fire_times_bank(times_rf, w_int, cfg.neuron_config(),
                                       backend=cfg.backend,
-                                      n_active_max=cfg.n_active_max,
-                                      policy=cfg.policy)          # (C, B, Q)
+                                      n_active_max=cfg.n_active_max)  # (C, B, Q)
         fire = sharding_specs.maybe_wsc(fire, _COL, _DP, None)
     with jax.named_scope("wta"):
         fire = jnp.swapaxes(fire, 0, 1)                       # (B, C, Q)

@@ -16,10 +16,8 @@ are jit/scan friendly; weights are a tuple of (C, Q, rf_total) arrays.
 Stateful streams: a recurrent layer (``TNNLayer.recurrent``) also sees its
 own previous-cycle output volley, so the network-level entry point is
 :func:`forward` — one call per gamma cycle threading an explicit per-layer
-``carry`` (previous outputs in, this cycle's outputs out). The historical
-``network_forward`` / ``network_forward_pipelined`` /
-``network_forward_with_densities`` trio are thin deprecated wrappers over
-it (DESIGN.md §6.3).
+``carry`` (previous outputs in, this cycle's outputs out; DESIGN.md
+§6.3).
 """
 
 from __future__ import annotations
@@ -31,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
-from repro import _deprecation
 from repro.core import coding
 from repro.core import layer as layer_mod
 from repro.core import neuron
@@ -64,9 +61,9 @@ class TNNNetwork:
     def column_counts(self) -> Tuple[int, ...]:
         """Per-layer column counts — the shape input to the Pallas mesh
         capability check; callers resolving one engine for the whole
-        stack (the serve engine) pass this as
-        ``EnginePolicy.resolve(column_counts=...)`` so the Pallas engines
-        degrade exactly when some layer cannot tile the mesh."""
+        stack (the serve engine) pass this to
+        :func:`repro.core.policy.resolve_engine`, so ``auto`` leaves the
+        Pallas engines exactly when some layer cannot tile the mesh."""
         return tuple(lc.n_columns for lc in self.layers)
 
 
@@ -220,24 +217,14 @@ def _forward_barriered(params, x, cfg, carry_in, with_densities
                          densities)
 
 
-def network_forward(params: Sequence[jax.Array], volleys: jax.Array,
-                    cfg: TNNNetwork
-                    ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
-    """Deprecated wrapper: use :func:`forward`. Returns (out, winners)."""
-    _deprecation.warn_deprecated("network_forward", "network.forward")
-    res = forward(params, volleys, cfg)
-    return res.out, res.winners
-
-
 def microbatch_split(batch: int, microbatches: int) -> Tuple[int, int]:
     """(effective M, rows per micro-batch) for a pipelined split (§5.4).
 
     Clamps ``microbatches`` to [1, batch], ceil-splits the rows, then
     recomputes the effective count (a ragged batch can need fewer
     micro-batches than requested). The single encoding of the split —
-    :func:`network_forward_pipelined` schedules with it and the serve
-    engine's per-stage stats (``TNNEngine``) mirror it, so the two can
-    never disagree about which rows form stage i.
+    :func:`forward` schedules with it and the serve engine reports it
+    (``TNNEngine.stats()['pipeline_microbatches']``).
     """
     if batch <= 0:
         return 0, 0
@@ -339,29 +326,6 @@ def _forward_pipelined(params, x, cfg, carry_in, m, rows) -> ForwardResult:
     return ForwardResult(out, winners, carry_out, None)
 
 
-def network_forward_pipelined(params: Sequence[jax.Array],
-                              volleys: jax.Array, cfg: TNNNetwork,
-                              microbatches: int = 2
-                              ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
-    """Deprecated wrapper: use :func:`forward` with ``microbatches=M``.
-    Returns (out, winners)."""
-    _deprecation.warn_deprecated("network_forward_pipelined",
-                                 "network.forward(..., microbatches=M)")
-    res = forward(params, volleys, cfg, microbatches=microbatches)
-    return res.out, res.winners
-
-
-def network_forward_with_densities(params: Sequence[jax.Array],
-                                   volleys: jax.Array, cfg: TNNNetwork):
-    """Deprecated wrapper: use :func:`forward` with
-    ``with_densities=True``. Returns (out, winners, densities)."""
-    _deprecation.warn_deprecated(
-        "network_forward_with_densities",
-        "network.forward(..., with_densities=True)")
-    res = forward(params, volleys, cfg, with_densities=True)
-    return res.out, res.winners, res.densities
-
-
 def measured_densities(params: Sequence[jax.Array], volleys: jax.Array,
                        cfg: TNNNetwork):
     """Per-layer measured input densities for one concrete batch — each
@@ -369,7 +333,7 @@ def measured_densities(params: Sequence[jax.Array], volleys: jax.Array,
     see — layer 0 reflects the input encoding's sparsity, deeper layers
     the 1-WTA thinning, at most one hot line per column so density <=
     1/n_neurons there) recorded on the same activations one forward pass
-    computes (§3.3 policy diagnostic). Host-side: entries are ``None``
+    computes (§3.3 diagnostic). Host-side: entries are ``None``
     under jit (``layer_input_density``)."""
     return forward(params, volleys, cfg, with_densities=True).densities
 

@@ -12,7 +12,7 @@ unmodified fused tick sweep on its local ``(C_local, B_local, ...)`` block
 construction, and the per-launch early-exit bound tightens to each shard's
 own last breakpoint.
 
-Preconditions (enforced by :func:`repro.core.neuron.pallas_shardable`
+Preconditions (enforced by :func:`repro.core.policy.resolve_engine`
 before dispatch, re-checked here):
 
   * an ambient mesh with a ``column`` axis is active (``compat.set_mesh``);
@@ -55,7 +55,7 @@ def _mesh_specs(n_columns: int, batch: int):
         raise ValueError(
             f"{n_columns} columns do not divide the {col!r} axis "
             f"(size {int(am.shape[col])}); use the jnp replication "
-            "fallback (neuron.pallas_shardable gates dispatch)")
+            "fallback (policy.resolve_engine gates dispatch)")
     dp = sharding_specs.ambient_fit(batch, sharding_specs.dp_spec_names())
     return am, col, dp
 

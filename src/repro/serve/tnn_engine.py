@@ -26,25 +26,22 @@ churn. ``retire`` hands the final carry back on the entry
 (``TNNRequest.final_state``), so a stream can be resubmitted later to
 continue where it left off.
 
-With ``backend="auto"`` the engine measures each batch's spike density
-host-side (before the jit boundary) and re-resolves the neuron-bank engine
-per step (DESIGN.md §3.3): sparse batches — GRF-encoded features, bursty
-clients, NO_SPIKE-padded free slots — take the event engine's O(s log s)
-breakpoint solve; dense batches keep the vectorized closed form. When a
-sparse engine is picked the engine also measures the batch's max active
-lines per receptive field, buckets it (``compaction.bucket_width``), and
-compiles the stack with static per-layer compaction widths
-(``network.sparse_widths``: measured bucket for layer 0, the 1-WTA
-structural bound for deeper layers) — so the jitted solve sorts ``2s``
-breakpoints, not ``2n``. The lane-aligned bucket ladder keeps distinct
-widths few, and the per-(engine, width) variant cache is a bounded LRU
-(``TNNServeConfig.max_jit_variants``; evictions surface in ``stats()``).
-All engines are bit-exact, so the policy is invisible in the outputs;
-``stats()`` reports the mean measured density and per-engine step counts.
-A step measures only when its resolution can use the result: on a TPU,
-where the fused Pallas kernel preempts ``auto``, and with an explicit
-non-sparse backend, the host skips the measurement
-(``stats()['steps_measured']`` counts the steps that took it).
+The neuron-bank engine is chosen once, at construction, inside the
+engine's mesh scope (:func:`repro.core.policy.resolve_engine`): ``auto``
+is the fused Pallas kernel on a TPU wherever every layer's column stack
+can run it, the closed form elsewhere; an explicit backend runs as named.
+The result is ``TNNEngine.engine``, and the network's ``auto`` layers are
+pinned to it (explicit per-layer backends are respected). A step measures
+its batch only for an explicit sparse engine (``event``,
+``pallas_compact``): the max active lines over layer 0's receptive
+fields, bucketed (``compaction.bucket_width``), compiles the stack with
+static per-layer compaction widths (``network.sparse_widths``: measured
+bucket for layer 0, the 1-WTA structural bound for deeper layers) — so the
+jitted solve sorts ``2s`` breakpoints, not ``2n``. The lane-aligned bucket
+ladder keeps the distinct widths, hence the jit variants, few
+(``stats()['steps_measured']`` counts the measured steps,
+``stats()['jit_variants']`` the variants). All engines are bit-exact, so
+the choice is invisible in the outputs.
 
 Empty slots carry all-``NO_SPIKE`` volleys: silent lines never fire a neuron,
 so padding rows are inert, and the batch shape stays static — one XLA
@@ -109,9 +106,6 @@ from repro.sharding import specs as sharding_specs
 from repro.train import checkpoint as CKPT
 from repro.train import fault_tolerance
 
-#: neuron-bank engines that consume a static compaction width under jit
-SPARSE_ENGINES = ("event", "pallas_compact")
-
 NO_SPIKE = int(coding.NO_SPIKE)
 
 #: a named host span on the profiler's clock (``TNNEngine.step``'s phases)
@@ -123,39 +117,20 @@ class TNNServeConfig:
     """Engine knobs: slot count (= batch rows) and neuron-bank backend."""
 
     n_slots: int = 8
-    #: fire_times_bank engine for every layer: scan | closed_form | event |
-    #: pallas | auto. ``auto`` re-resolves every step from the *measured*
-    #: batch activity (host-side, before the jit boundary; not measured
-    #: where the TPU Pallas kernel preempts) through the
-    #: configured ``policy`` — NO_SPIKE-padded slot batches are exactly
-    #: the sparse case the event engine wins on. All engines are
-    #: bit-exact, so the policy never changes outputs.
+    #: fire_times_bank engine for every ``auto`` layer: scan | closed_form
+    #: | event | pallas | pallas_compact | auto, resolved once at
+    #: construction (``TNNEngine.engine``). ``auto`` is pallas on a TPU
+    #: wherever the kernel can run, closed_form elsewhere. All engines are
+    #: bit-exact, so the choice never changes outputs.
     backend: neuron.Backend = "auto"
-    #: how ``auto`` picks: ``"cost"`` (default) ranks engines and
-    #: compaction widths by the calibrated analytic predictor
-    #: (:func:`repro.core.policy.default_policy`, memoized — per-step
-    #: resolution is a handful of float ops); ``"density"`` is the legacy
-    #: ``DENSITY_EVENT_MAX`` threshold escape hatch; or a custom
-    #: :class:`repro.core.policy.EnginePolicy`. Validated at construction
-    #: like backend names (DESIGN.md §3.7).
-    policy: typing.Union[str, engine_policy.EnginePolicy] = "cost"
     #: gamma-cycle pipeline micro-batches per step (DESIGN.md §5.4): 1 =
     #: the barriered schedule; M > 1 streams the slot batch
     #: through the layer stack in M micro-batches
     #: (``network.forward(..., microbatches=M)``) so layer l works micro-batch
     #: t while layer l+1 works micro-batch t-1. Bit-exact for every
-    #: backend; the density/width measurements stay host-side, taken per
-    #: micro-batch (``stats()`` reports per-stage means).
+    #: backend; a sparse engine's width is measured on the whole slot
+    #: batch, so it covers every micro-batch.
     pipeline_microbatches: int = 1
-    #: LRU cap on the lazily-compiled per-(engine, width) jit variants
-    #: (``_fwd_for``). The lane-aligned ``compaction.bucket_width`` ladder
-    #: already bounds distinct widths, but a long-lived service crossing
-    #: many (engine, bucket) pairs would still accumulate compiled
-    #: executables without bound — beyond this many variants the least
-    #: recently used is dropped (and recompiled if needed again;
-    #: ``stats()['jit_evictions']`` counts drops). The default compiled
-    #: step (``_fwd``) is pinned and never counts against the cap.
-    max_jit_variants: int = 8
     #: admission control: cap on the pending queue (None = unbounded).
     #: With a cap set, ``submit`` raises
     #: :class:`repro.serve.slots.QueueFull` once the queue holds this many
@@ -222,10 +197,8 @@ class TNNRequest:
     outputs: List[np.ndarray] = dataclasses.field(default_factory=list)
     cursor: int = 0
     #: fraction of this request's lines carrying an in-cycle spike
-    #: (measured at submit; the sparsity the auto policy exploits)
+    #: (measured at submit)
     density: float = 0.0
-    #: engines the auto policy actually served this request's cycles with
-    backends: set = dataclasses.field(default_factory=set)
     #: carry to seed the slot with at admission (stream continuation);
     #: None = fresh all-silent state (``TNNEngine.submit(initial_state=)``)
     initial_state: Optional[CarryState] = None
@@ -257,7 +230,8 @@ class TNNEngine:
     2. ``batch``: live slots contribute their next volley; empty rows are
        all-``NO_SPIKE`` (inert).
     3. ``fire``: one jit ``network.forward`` over ``(B, n_inputs)``
-       threading the live slots' recurrent carries.
+       threading the live slots' recurrent carries, on the neuron-bank
+       engine resolved once at construction (``engine``).
     4. ``retire``: exhausted requests leave their slots immediately.
     """
 
@@ -270,9 +244,8 @@ class TNNEngine:
         resume: bool = False,
     ):
         scfg = scfg or TNNServeConfig()
-        # strict construction-time validation: a typo'd backend used to
-        # surface only deep inside fire_times_bank on the first step (or
-        # never, for a layer the density policy happened to re-pin)
+        # strict construction-time validation: a typo'd backend would
+        # otherwise surface only deep inside fire_times_bank at trace time
         valid = typing.get_args(neuron.Backend)
         for name, where in [(scfg.backend, "TNNServeConfig.backend")] + [
                 (lc.backend, f"net.layers[{i}].backend")
@@ -280,25 +253,28 @@ class TNNEngine:
             if name not in valid:
                 raise ValueError(
                     f"{where}={name!r}: expected one of {valid}")
-        # policy validation mirrors the backend check: a typo'd policy
-        # spec fails here, not on the first step (get_policy raises); the
-        # memoized accessors make this free for the common string specs
-        self._policy = engine_policy.get_policy(scfg.policy)
-        if scfg.backend != "auto":
-            # pin only the layers that delegated the choice: explicit
-            # per-layer backends are respected (mirrors _fwd_for)
-            layers = [
-                lc if lc.backend != "auto" else dataclasses.replace(lc, backend=scfg.backend)
-                for lc in net.layers
-            ]
-            net = network.make_network(layers)
-        self.net = net
-        self.scfg = scfg
         #: optional ("data", "column") device mesh (sharding.specs.tnn_mesh):
         #: weights live column-sharded, each step's slot batch is placed
         #: under the data spec, and the jitted stack traces inside the mesh
         #: scope so the layer constraints bind (DESIGN.md §6.4)
         self.mesh = mesh
+        # the one engine decision, inside the mesh scope with every layer's
+        # column count: a Pallas engine survives exactly when each layer
+        # clears the per-kernel capability check, and an explicit one that
+        # cannot run raises here (DESIGN.md §6.4)
+        with self._mesh_scope():
+            #: the neuron-bank engine every ``auto`` layer runs
+            self.engine = engine_policy.resolve_engine(scfg.backend, net.column_counts)
+        # pin only the layers that delegated the choice: explicit
+        # per-layer backends are respected
+        net = network.make_network(
+            [
+                lc if lc.backend != "auto" else dataclasses.replace(lc, backend=self.engine)
+                for lc in net.layers
+            ]
+        )
+        self.net = net
+        self.scfg = scfg
         self.params = self._place_params(params)
         if mesh is not None:
             self._batch_sharding = network.data_sharding(net, mesh, scfg.n_slots)
@@ -330,46 +306,16 @@ class TNNEngine:
             raise ValueError(
                 f"pipeline_microbatches must be >= 1, got {scfg.pipeline_microbatches}"
             )
-        # effective micro-batch split — network.microbatch_split is the
-        # single encoding, shared with network.forward, so the
-        # host-side _stage_rows (per-stage density measurement) can never
-        # disagree with the compiled pipeline schedule
-        self.n_stages, rows = network.microbatch_split(
+        # effective micro-batch count — network.microbatch_split is the
+        # single encoding, shared with network.forward
+        self.n_stages, _ = network.microbatch_split(
             scfg.n_slots, scfg.pipeline_microbatches
         )
-        self._stage_rows = [
-            (i * rows, min((i + 1) * rows, scfg.n_slots)) for i in range(self.n_stages)
-        ]
-        self._stage_density_sums = [0.0] * self.n_stages
         self._fwd = jax.jit(self._forward_fn(net))
-        #: per-layer column counts — the shape input to the Pallas mesh
-        #: capability check; EnginePolicy.resolve passes it so a mesh +
-        #: dividing columns keeps the shard_map fast path
-        self._column_counts = net.column_counts
-        #: layer-0 bank workload for the cost predictor: every slot row
-        #: through every layer-0 neuron (the dominant bank; deeper layers
-        #: see post-WTA volleys, sparser by construction)
-        self._bank_shape = engine_policy.BankShape(
-            pairs=scfg.n_slots * net.layers[0].n_columns
-            * net.layers[0].n_neurons,
-            n_lines=net.layers[0].rf_total,
-            t_steps=net.layers[0].t_steps)
-        # activity-less resolution = the engine self._fwd compiles to; the
-        # per-step policy swaps in a sparse engine via _fwd_for (resolved
-        # inside the mesh scope with the network's column counts, so the
-        # Pallas engines survive exactly when every layer clears the
-        # per-kernel capability check — DESIGN.md §6.4)
-        with self._mesh_scope():
-            self._default_engine = self._policy.resolve(
-                scfg.backend, column_counts=self._column_counts).engine
-        if scfg.max_jit_variants < 1:
-            raise ValueError(
-                f"max_jit_variants must be >= 1, got {scfg.max_jit_variants}")
-        # LRU over the lazily-compiled (engine, width) variants; the
-        # default self._fwd lives outside it and is never evicted
-        self._fwd_alt: "collections.OrderedDict[tuple, object]" = \
-            collections.OrderedDict()
-        self._jit_evictions = 0
+        # the other compiled steps, keyed (layer-0 width, learn): a sparse
+        # engine's bucketed widths and the STDP step. The bucket ladder
+        # under layer 0's rf_total bounds them, so the dict stays small.
+        self._variants: Dict[Tuple[Optional[int], bool], object] = {}
         self._t_steps = net.layers[0].t_steps
         # layer-0 receptive-field line ids, host-side: the per-step sparse
         # width is measured on the gathered view the neuron banks will see
@@ -380,15 +326,8 @@ class TNNEngine:
         self.n_steps = 0
         self.n_volleys = 0
         self._run_s = 0.0
-        self._density_sum = 0.0
-        #: steps whose batch the host measured (``_measures``); the
-        #: density means average over these
+        #: steps whose batch the host measured (explicit sparse engines)
         self._steps_measured = 0
-        self._backend_steps: Dict[str, int] = {}
-        # predicted-vs-chosen accounting: what the cost predictor wanted
-        # (pre mesh degradation) vs what ran, plus its runtime estimates
-        self._predicted_steps: Dict[str, int] = {}
-        self._predicted_us_sum: Dict[str, float] = {}
         # ---------------------------------- learning + durability (§5.5)
         if scfg.stdp_every < 1:
             raise ValueError(f"stdp_every must be >= 1, got "
@@ -442,7 +381,7 @@ class TNNEngine:
         Inference (``learn=False``): ``network.forward`` with the engine's
         micro-batch count — the barriered schedule at M=1, the §5.4
         pipelined schedule above it, bit-exact either way, so every jit
-        variant (``_fwd_for``) shares it. Signature ``(params, volleys,
+        variant (:meth:`_fwd_for`) shares it. Signature ``(params, volleys,
         carry) -> (out, carry_out)``; the carry tuple's ``None`` entries
         (feedforward layers, or every layer in a stateless network) vanish
         from the jit pytree, so a feedforward engine compiles the exact
@@ -607,12 +546,7 @@ class TNNEngine:
         self.n_steps = 0
         self.n_volleys = 0
         self._run_s = 0.0
-        self._density_sum = 0.0
         self._steps_measured = 0
-        self._stage_density_sums = [0.0] * self.n_stages
-        self._backend_steps = {}
-        self._predicted_steps = {}
-        self._predicted_us_sum = {}
         self.pool.n_retired = 0
         self.pool.n_rejected = 0
         self.pool.n_submitted = self.pool.n_live + self.pool.n_pending
@@ -681,8 +615,8 @@ class TNNEngine:
 
     def _place(self, batch: np.ndarray) -> jax.Array:
         """Host batch -> device(s): under a mesh the (B, n_inputs) block is
-        placed batch-over-``data`` before the jit boundary (the density and
-        width measurements above stay host-side, on the numpy batch)."""
+        placed batch-over-``data`` before the jit boundary (a sparse
+        engine's width measurement stays host-side, on the numpy batch)."""
         if self._batch_sharding is None:
             return jnp.asarray(batch)
         return jax.device_put(batch, self._batch_sharding)
@@ -697,76 +631,41 @@ class TNNEngine:
             for c, sh in zip(carry_np, self._carry_shardings)
         )
 
-    def _measures(self) -> bool:
-        """Whether this step's resolution can use a measured batch: ``auto``
-        off the TPU Pallas fast path ranks engines by it, and an explicit
-        sparse engine takes its compaction width from it. Asked inside the
-        mesh scope, where the policy's Pallas capability check reads the
-        mesh the step runs under."""
-        return (self.scfg.backend in SPARSE_ENGINES
-                or self._policy.wants_density(self.scfg.backend,
-                                              self._column_counts))
-
     def _layer0_active(self, batch: np.ndarray) -> int:
         """Max active-line count over the batch's layer-0 receptive
-        fields (exact measurement, so no active line can drop; the policy
-        buckets it onto the compaction ladder — ``width_for``)."""
+        fields (exact measurement, so no active line can drop; the step
+        buckets it onto the compaction ladder)."""
         active = batch[:, self._rf0] < self._t_steps  # (B, C, rf)
         return int(active.sum(axis=-1).max()) if active.size else 0
 
-    def _fwd_for(
-        self,
-        engine: str,
-        first_width: Optional[int] = None,
-        learn: bool = False,
-    ):
-        """jit ``network.forward`` step for a density-resolved engine.
+    def _fwd_for(self, first_width: Optional[int] = None, learn: bool = False):
+        """The jitted step for a layer-0 compaction width and a learn flag.
 
-        The default resolution uses the compiled ``self._fwd``; any other
-        resolution lazily compiles a variant with the network's
-        ``backend="auto"`` layers pinned to ``engine`` (explicit per-layer
-        backends are respected). Sparse engines additionally pin static
-        compaction widths (``network.sparse_widths`` seeded with the
-        measured+bucketed ``first_width``), so the jitted stack runs the
-        compacted solve; distinct buckets get distinct compiles, few by
-        construction (the lane-aligned ``compaction.bucket_width`` ladder)
-        and capped overall: the variants live in an LRU of
-        ``scfg.max_jit_variants`` entries — an over-cap compile drops the
-        least recently used executable (``stats()['jit_evictions']``).
-
-        ``learn=True`` selects the STDP step (``_forward_fn(..., learn)``:
-        forward + weight update, weights as explicit jit state). Learning
-        variants share the same LRU, keyed ``(engine, width, learn)`` —
-        at most double the variant population, same cap, and the weight
-        update itself never forces a compile (weights are arguments).
+        ``(None, False)`` is the compiled ``self._fwd``. Any other key
+        lazily compiles a variant: sparse layers get static compaction
+        widths (``network.sparse_widths`` seeded with the measured and
+        bucketed ``first_width``), so the jitted stack runs the compacted
+        solve; ``learn=True`` selects the STDP step (``_forward_fn(...,
+        learn)``: forward + weight update, weights as explicit jit state,
+        so a weight update never forces a compile).
         """
-        if engine == self._default_engine and first_width is None and not learn:
+        if first_width is None and not learn:
             return self._fwd
-        key = (engine, first_width, learn)
-        if key in self._fwd_alt:
-            self._fwd_alt.move_to_end(key)
-            return self._fwd_alt[key]
-        widths = (
-            network.sparse_widths(self.net, first_width)
-            if first_width is not None
-            else (None,) * len(self.net.layers)
-        )
-        layers = []
-        for lc, width in zip(self.net.layers, widths):
-            eff = engine if lc.backend == "auto" else lc.backend
-            layers.append(
-                dataclasses.replace(
-                    lc,
-                    backend=eff,
-                    n_active_max=width if eff in SPARSE_ENGINES else lc.n_active_max,
-                )
+        key = (first_width, learn)
+        fwd = self._variants.get(key)
+        if fwd is not None:
+            return fwd
+        net = self.net
+        if first_width is not None:
+            sparse = engine_policy.SPARSE_ENGINES
+            widths = network.sparse_widths(net, first_width)
+            net = network.make_network(
+                [
+                    dataclasses.replace(lc, n_active_max=w) if lc.backend in sparse else lc
+                    for lc, w in zip(net.layers, widths)
+                ]
             )
-        pinned = network.make_network(layers)
-        fwd = jax.jit(self._forward_fn(pinned, learn=learn))
-        self._fwd_alt[key] = fwd
-        while len(self._fwd_alt) > self.scfg.max_jit_variants:
-            self._fwd_alt.popitem(last=False)
-            self._jit_evictions += 1
+        fwd = self._variants[key] = jax.jit(self._forward_fn(net, learn))
         return fwd
 
     def step(self) -> List[TNNRequest]:
@@ -810,57 +709,15 @@ class TNNEngine:
                                 c[idx] = s
             with self._mesh_scope():
                 with _span("tnn.measure"):
-                    density = max_active = None
-                    if self._measures():
-                        # measured batch density (host-side — the jit
-                        # boundary can't see it): NO_SPIKE-padded free
-                        # slots count as silent lines, which is precisely
-                        # why partially-filled batches resolve to the
-                        # event path. Under pipelining the same
-                        # measurement lands per micro-batch, so stats()
-                        # can show each stage's traffic; the step-level
-                        # resolution stays whole-batch (one compiled
-                        # schedule serves all stages).
-                        density = float(np.mean(batch < self._t_steps))
-                        self._density_sum += density
+                    # only an explicit sparse engine reads the batch: its
+                    # static compaction width is bucketed from this
+                    # batch's own layer-0 receptive fields (exact-covering,
+                    # so no active line drops)
+                    width = None
+                    if self.engine in engine_policy.SPARSE_ENGINES:
+                        width = compaction.bucket_width(self._layer0_active(batch))
                         self._steps_measured += 1
-                        if self.n_stages > 1:
-                            for i, (lo, hi) in enumerate(self._stage_rows):
-                                self._stage_density_sums[i] += float(
-                                    np.mean(batch[lo:hi] < self._t_steps))
-                        # the measured layer-0 active count feeds both
-                        # the cost ranking and the compaction bucket
-                        # (width stays exact-covering: no active line
-                        # drops)
-                        max_active = self._layer0_active(batch)
                 with _span("tnn.resolve"):
-                    # resolution inside the mesh scope with the network's
-                    # column counts: the policy sees the mesh AND the
-                    # per-kernel Pallas capability, so the Pallas engines
-                    # survive when every layer's columns tile the mesh and
-                    # degrade only in the replication-fallback case;
-                    # Resolution.engine is what will actually run, so
-                    # stats/jit-variants record the truth.
-                    res = self._policy.resolve(
-                        self.scfg.backend, density=density,
-                        max_active=max_active,
-                        column_counts=self._column_counts,
-                        shape=self._bank_shape)
-                    engine = res.engine
-                    self._backend_steps[engine] = \
-                        self._backend_steps.get(engine, 0) + 1
-                    if res.predicted_us:
-                        want = min(res.predicted_us,
-                                   key=res.predicted_us.__getitem__)
-                        self._predicted_steps[want] = \
-                            self._predicted_steps.get(want, 0) + 1
-                        for name, us in res.predicted_us.items():
-                            self._predicted_us_sum[name] = \
-                                self._predicted_us_sum.get(name, 0.0) + us
-                    # sparse engines compile against a static compaction
-                    # width bucketed from this batch's own receptive-field
-                    # measurement
-                    width = res.width if engine in SPARSE_ENGINES else None
                     learn = self._learn_gate()
                 with _span("tnn.h2d"):
                     batch_dev = self._place(batch)
@@ -874,12 +731,12 @@ class TNNEngine:
                         # column-sharded (layer_step pins it via
                         # specs.tnn_param_axes)
                         out_dev, carry_dev, new_params = self._fwd_for(
-                            engine, width, learn=True
+                            width, learn=True
                         )(self.params, batch_dev, carry_in, self._stdp_key())
                         self.params = new_params
                         self.n_stdp_updates += 1
                     else:
-                        out_dev, carry_dev = self._fwd_for(engine, width)(
+                        out_dev, carry_dev = self._fwd_for(width)(
                             self.params, batch_dev, carry_in
                         )
                 with _span("tnn.fetch"):
@@ -892,7 +749,6 @@ class TNNEngine:
             with _span("tnn.retire"):
                 for idx, entry in live:
                     req = entry.item
-                    req.backends.add(engine)
                     # copy: out[idx] is a view that would pin the whole
                     # (B, C, Q) batch array for the life of the request
                     req.outputs.append(out[idx].copy())
@@ -960,32 +816,11 @@ class TNNEngine:
         if self.n_steps > 0:
             denom = self.n_steps * self.scfg.n_slots
             out["slot_occupancy"] = self.n_volleys / denom
-        # batch densities: means over the steps the host measured (none on
-        # the TPU Pallas fast path, which needs no measurement)
+        # steps whose batch the host measured (explicit sparse engines)
         out["steps_measured"] = float(self._steps_measured)
-        if self._steps_measured > 0:
-            out["density_mean"] = self._density_sum / self._steps_measured
         out["pipeline_microbatches"] = float(self.n_stages)
-        if self._steps_measured > 0 and self.n_stages > 1:
-            for i, total in enumerate(self._stage_density_sums):
-                out[f"density_stage{i}_mean"] = \
-                    total / self._steps_measured
-        for engine, steps in self._backend_steps.items():
-            out[f"steps_{engine}"] = float(steps)
-        # predicted-vs-chosen: which engine the cost predictor ranked
-        # cheapest each step (pre mesh degradation) and its mean runtime
-        # estimate — divergence from steps_<engine> means degradation or
-        # an explicit backend overrode the prediction (DESIGN.md §3.7)
-        out["policy_mode"] = 1.0 if self._policy.mode == "cost" else 0.0
-        for engine, steps in self._predicted_steps.items():
-            out[f"steps_predicted_{engine}"] = float(steps)
-        for engine, us in self._predicted_us_sum.items():
-            if self.n_steps > 0:
-                out[f"predicted_us_mean_{engine}"] = us / self.n_steps
-        # compiled-variant accounting: live LRU entries + total drops (the
-        # default compiled step is pinned outside the cache)
-        out["jit_variants"] = float(len(self._fwd_alt))
-        out["jit_evictions"] = float(self._jit_evictions)
+        # compiled steps besides the default self._fwd
+        out["jit_variants"] = float(len(self._variants))
         # §5.5 learning + durability counters (step_id is the persistent
         # counter snapshots carry; n_steps above is the resettable stat)
         out["step_id"] = float(self.step_id)

@@ -269,7 +269,7 @@ def tnn_column_size() -> int:
     """Size of the ambient mesh's ``column`` axis (1 when no mesh is
     active or the mesh has no such axis). The divisor a column count must
     divide for the shard_map Pallas fast path to tile it
-    (:func:`repro.core.neuron.pallas_shardable`)."""
+    (:func:`repro.core.policy.resolve_engine`)."""
     am = compat.get_abstract_mesh()
     if TNN_COLUMN_AXIS not in am.axis_names:
         return 1

@@ -15,14 +15,12 @@ CORPUS = REPO / "tests" / "lint_corpus"
 # one bad file per rule -> the only slug it may emit
 CORPUS_SLUGS = {
     "bad_private_jax.py": "private-jax",
-    "bad_deprecated_forward.py": "deprecated-forward",
     "bad_host_leak.py": "host-leak-in-jit",
     "bad_pallas_lane.py": "pallas-lane",
     "bad_pallas_smem_order.py": "pallas-smem-order",
     "bad_pallas_interpret.py": "pallas-interpret-literal",
     "core/bad_unplaced.py": "core-unplaced",
     "bad_raw_env.py": "raw-env",
-    "bad_deprecated_resolution.py": "deprecated-resolution",
 }
 
 

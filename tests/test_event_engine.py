@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core import coding, neuron, policy
+from repro.core import coding, neuron
 
 DENDRITES = ("pc_conventional", "pc_compact", "sorting_pc", "catwalk")
 NO_SPIKE = int(coding.NO_SPIKE)
@@ -181,56 +181,6 @@ def test_event_catwalk_clip_changes_fire_time():
     pc = _assert_all_engines_agree(times, w, cfg_pc)
     cw = _assert_all_engines_agree(times, w, cfg_cw)
     assert int(pc[0, 0]) < int(cw[0, 0])          # clip delays the spike
-
-
-# ------------------------------------------------------------- auto policy
-def test_density_mode_resolution_policy():
-    legacy = policy.density_policy()
-    assert legacy.resolve("auto", density=0.1).requested in \
-        ("event", "pallas")
-    if jax.default_backend() == "cpu":
-        assert legacy.resolve("auto", density=0.1).requested == "event"
-        assert legacy.resolve(
-            "auto", density=neuron.DENSITY_EVENT_MAX).requested == "event"
-        assert legacy.resolve("auto", density=0.9).requested == \
-            "closed_form"
-        assert legacy.resolve("auto").requested == "closed_form"
-    # explicit choices are never overridden by density
-    assert legacy.resolve("scan", density=0.01).engine == "scan"
-    assert legacy.resolve("closed_form", density=0.01).engine == \
-        "closed_form"
-
-
-def test_cost_mode_resolution_policy():
-    """The default cost policy: sparse workloads pick the event engine,
-    the densest bucket flips to the closed form, unknown stays dense."""
-    pol = policy.default_policy()
-    shape = policy.BankShape(pairs=64 * 64, n_lines=64, t_steps=64)
-    if jax.default_backend() == "cpu":
-        sparse = pol.resolve("auto", density=0.1, shape=shape)
-        assert sparse.requested == "event"
-        assert sparse.width == 8
-        assert sparse.predicted_us["event"] < \
-            sparse.predicted_us["closed_form"]
-        dense = pol.resolve("auto", max_active=64, shape=shape)
-        assert dense.requested == "closed_form"
-        # unknown workload (tracing): the dense fallback, no prediction
-        blind = pol.resolve("auto")
-        assert blind.requested == "closed_form"
-        assert blind.predicted_us == {}
-    assert pol.resolve("scan", density=0.01, shape=shape).engine == "scan"
-
-
-def test_fire_times_bank_auto_engages_event_on_sparse_concrete_input():
-    """Concrete sparse volleys through backend="auto" must produce the
-    same fire times regardless of which engine the policy picks."""
-    cfg = neuron.NeuronConfig(n_inputs=16, threshold=6, t_steps=24,
-                              dendrite="catwalk", k=2)
-    times = _sparse_volleys(11, 5, 16, 20, 0.9)
-    w = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, 8)
-    want = neuron.fire_times_bank(times, w, cfg, backend="scan")
-    got = neuron.fire_times_bank(times, w, cfg, backend="auto")
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
 # ------------------------------------------------------ property-based sweep

@@ -65,9 +65,8 @@ def test_fire_times_bank_shape_validation():
 
 def test_resolve_auto_cpu_without_measurement_is_closed_form():
     if jax.default_backend() == "cpu":
-        assert policy.default_policy().resolve("auto").engine == \
-            "closed_form"
-    assert policy.default_policy().resolve("scan").engine == "scan"
+        assert policy.resolve_engine("auto") == "closed_form"
+    assert policy.resolve_engine("scan") == "scan"
 
 
 # ------------------------------------------------------------- rnl clip out
@@ -212,7 +211,7 @@ def test_network_shape_validation():
     assert net.n_inputs == 16 and net.n_outputs == 3
 
 
-def test_network_forward_feeds_wta_times_forward():
+def test_forward_feeds_wta_times_forward():
     l1 = _layer_cfg(n_columns=2, rf_size=8, n_neurons=4, threshold=6)
     l2 = _layer_cfg(n_columns=1, rf_size=8, n_neurons=3, threshold=3)
     net = network.make_network([l1, l2])

@@ -1,251 +1,87 @@
-"""EnginePolicy: the calibrated cost model against the committed sweeps,
-the deprecated resolution wrappers, the policy-threaded serve config, and
-the reproduced paper tables (DESIGN.md §3.7)."""
+"""Engine resolution (``policy.resolve_engine``): the decision table over
+backend name x platform x mesh, and the reproduced paper tables
+(DESIGN.md §3.7).
+
+The platform and the mesh are patched in process; real meshes are covered
+by the subprocess suites (tests/test_sharding_tnn.py,
+tests/test_sharding_pallas.py).
+"""
 
 import json
+import types
 from pathlib import Path
 
 import jax
-import numpy as np
 import pytest
 
-from repro._deprecation import ReproDeprecationWarning
-from repro.core import coding, compaction, layer, network, policy
-from repro.core import neuron
-from repro.serve import tnn_engine
+from repro.core import policy
+from repro.sharding import compat
 
 REPO = Path(__file__).resolve().parent.parent
 ARTIFACTS = REPO / "benchmarks" / "artifacts"
 
-NO_SPIKE = int(coding.NO_SPIKE)
+BACKENDS = ("auto", "scan", "closed_form", "event", "pallas",
+            "pallas_compact")
+
+#: column counts per mesh case, against a 4-wide ``column`` axis
+MESHES = {"none": None, "tiling": (8, 4), "non-tiling": (8, 5)}
 
 
-def _sparsity_artifact():
-    with open(ARTIFACTS / "BENCH_sparsity.json") as f:
-        return json.load(f)
+def _expected(backend, platform, mesh):
+    """The decision table of DESIGN.md §3.7: an engine name, or
+    ``ValueError`` where an explicit Pallas engine cannot run."""
+    fits = mesh != "non-tiling"
+    if backend == "auto":
+        return "pallas" if platform == "tpu" and fits else "closed_form"
+    if backend in ("pallas", "pallas_compact") and not fits:
+        return ValueError
+    return backend
 
 
-def _sweep_cells(artifact):
-    """density -> {backend: measured us} from the committed sweep."""
-    cells = {}
-    for row in artifact["results"]:
-        d, b = row.get("density"), row.get("backend")
-        if d is None or b is None:
-            continue
-        cells.setdefault(float(d), {})[b] = float(row["us_per_call"])
-    return cells
-
-
-def _sweep_shape(artifact):
-    """The sweep's bank workload (B=Q=n=T=64 -> pairs=4096)."""
-    assert artifact["metadata"]["bank_shape"] == "B64xQ64xn64xT64"
-    return policy.BankShape(pairs=64 * 64, n_lines=64, t_steps=64)
-
-
-# ------------------------------------------------- cost model vs sweep
-
-def test_committed_sweep_is_full_size():
-    art = _sparsity_artifact()
-    assert art["smoke"] is False, "calibration artifact must be full-size"
-    assert len(_sweep_cells(art)) >= 6
-
-
-@pytest.mark.parametrize("fresh_fit", [False, True],
-                         ids=["committed-coeffs", "fresh-fit"])
-def test_cost_policy_matches_measured_fastest_on_every_cell(fresh_fit):
-    """On every committed density cell the predictor's event-vs-closed_form
-    argmin agrees with the measured-fastest engine — both for the committed
-    default coefficients and for a fit re-derived from the artifact."""
-    art = _sparsity_artifact()
-    shape = _sweep_shape(art)
-    if fresh_fit:
-        coeffs = policy.fit_coefficients(
-            art["results"], pairs=shape.pairs, n_lines=shape.n_lines,
-            t_steps=shape.t_steps)
-        pol = policy.EnginePolicy(coeffs=coeffs)
+@pytest.mark.parametrize("mesh", list(MESHES))
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_resolve_engine_decision_table(backend, platform, mesh,
+                                       monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if mesh != "none":
+        fake = types.SimpleNamespace(axis_names=("data", "column"),
+                                     shape={"data": 2, "column": 4})
+        monkeypatch.setattr(compat, "get_abstract_mesh", lambda: fake)
+    assert policy.mesh_active() == (mesh != "none")
+    counts = MESHES[mesh] or (8, 5)
+    want = _expected(backend, platform, mesh)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="backend='auto'"):
+            policy.resolve_engine(backend, counts)
     else:
-        pol = policy.default_policy()
-    for density, cell in sorted(_sweep_cells(art).items()):
-        measured = {b: us for b, us in cell.items()
-                    if b in ("event", "closed_form")}
-        fastest = min(measured, key=measured.__getitem__)
-        res = pol.resolve("auto",
-                          max_active=round(density * shape.n_lines),
-                          shape=shape)
-        assert res.requested == fastest, (
-            f"density {density}: policy chose {res.requested} "
-            f"({res.predicted_us}), measured fastest is {fastest} "
-            f"({measured})")
-        assert set(res.predicted_us) == {"event", "closed_form"}
+        assert policy.resolve_engine(backend, counts) == want
 
-
-def test_cost_policy_matches_or_beats_density_threshold():
-    """Summed over the committed sweep, the cost-driven picks are at least
-    as fast as the hand-tuned DENSITY_EVENT_MAX threshold's picks (the
-    paper-style win: the model moves the boundary to density 0.5)."""
-    art = _sparsity_artifact()
-    shape = _sweep_shape(art)
-    cost_pol, dens_pol = policy.default_policy(), policy.density_policy()
-    cost_total = dens_total = 0.0
-    for density, cell in sorted(_sweep_cells(art).items()):
-        s = round(density * shape.n_lines)
-        cost_pick = cost_pol.resolve(
-            "auto", max_active=s, shape=shape).requested
-        dens_pick = dens_pol.resolve(
-            "auto", density=density, shape=shape).requested
-        assert cost_pick in cell and dens_pick in cell
-        cost_total += cell[cost_pick]
-        dens_total += cell[dens_pick]
-        assert cell[cost_pick] <= cell[dens_pick], (
-            f"density {density}: cost mode picked {cost_pick} "
-            f"({cell[cost_pick]:.0f}us) vs threshold {dens_pick} "
-            f"({cell[dens_pick]:.0f}us)")
-    assert cost_total < dens_total
-
-
-def test_fit_coefficients_rejects_empty_rows():
-    with pytest.raises(ValueError, match="closed_form rows"):
-        policy.fit_coefficients([], pairs=4096, n_lines=64, t_steps=64)
-
-
-# --------------------------------------------------- resolution + width
 
 def test_resolve_explicit_backend_passes_through():
-    pol = policy.default_policy()
-    for b in ("scan", "closed_form", "event"):
-        res = pol.resolve(b, density=0.01,
-                          shape=policy.BankShape(4096, 64, 64))
-        assert res.engine == res.requested == b
-        assert res.predicted_us == {}
+    for b in BACKENDS[1:]:
+        assert policy.resolve_engine(b) == b
+        assert policy.resolve_engine(b, 4) == b
+    with pytest.raises(ValueError, match="unknown backend"):
+        policy.resolve_engine("fastest")
 
 
-def test_resolve_unknown_workload_stays_dense():
-    res = policy.default_policy().resolve("auto")
-    assert res.requested == "closed_form"
-    assert res.width is None and res.predicted_us == {}
-
-
-def test_width_for_is_smallest_covering_bucket():
-    pol = policy.default_policy()
-    shape = policy.BankShape(4096, 64, 64)
-    for s in (1, 2, 3, 7, 8, 9, 31, 64):
-        w = pol.width_for(s, shape)
-        assert w == compaction.bucket_width(s)
-        assert w >= min(s, shape.n_lines)
-
-
-def test_sparse_resolution_carries_width():
-    res = policy.default_policy().resolve(
-        "auto", max_active=5, shape=policy.BankShape(4096, 64, 64))
-    assert res.requested == "event"
-    assert res.width == compaction.bucket_width(5)
-
-
-def test_get_policy_and_mode_validation():
-    assert policy.get_policy("cost") is policy.default_policy()
-    assert policy.get_policy("density") is policy.density_policy()
-    custom = policy.EnginePolicy(mode="density")
-    assert policy.get_policy(custom) is custom
-    with pytest.raises(ValueError, match="unknown engine policy"):
-        policy.get_policy("fastest")
-    with pytest.raises(ValueError, match="unknown policy mode"):
-        policy.EnginePolicy(mode="adaptive")
-
-
-def test_policy_is_hashable_config_material():
-    assert hash(policy.default_policy()) == hash(policy.EnginePolicy())
-    assert policy.default_policy() != policy.density_policy()
-
-
-# ------------------------------------------------- deprecated wrappers
-
-def test_resolve_backend_wrapper_warns_and_delegates():
-    with pytest.warns(ReproDeprecationWarning, match="resolve_backend"):
-        got = neuron.resolve_backend("auto", 0.1)  # repro-lint: allow[deprecated-resolution]
-    want = policy.density_policy().resolve("auto", density=0.1).requested
-    assert got == want
-    with pytest.warns(ReproDeprecationWarning):
-        assert neuron.resolve_backend("scan") == "scan"  # repro-lint: allow[deprecated-resolution]
-
-
-def test_effective_engine_wrapper_warns_and_delegates():
-    with pytest.warns(ReproDeprecationWarning, match="effective_engine"):
-        got = neuron.effective_engine("event", 4)  # repro-lint: allow[deprecated-resolution]
-    assert got == "event"
-
-
-def test_pallas_shardable_wrapper_warns_and_delegates():
-    with pytest.warns(ReproDeprecationWarning, match="pallas_shardable"):
-        got = neuron.pallas_shardable(8)  # repro-lint: allow[deprecated-resolution]
-    assert got is True  # no mesh active in-process
-
-
-# ------------------------------------------------- serve-path threading
-
-def _small_net():
-    l1 = layer.TNNLayer(n_columns=2, rf_size=4, n_neurons=3, threshold=5,
-                        t_steps=12, dendrite="catwalk", k=2)
-    return network.make_network([l1])
-
-
-def _streams(net, n_req, seed=0):
-    rng = np.random.default_rng(seed)
-    out = []
-    for _ in range(n_req):
-        t = rng.integers(0, 20, size=(2, net.n_inputs))
-        out.append(np.where(t >= 10, NO_SPIKE, t).astype(np.int32))
-    return out
-
-
-def test_serve_config_rejects_bad_policy_at_construction():
-    net = _small_net()
-    params = network.init_network(jax.random.PRNGKey(0), net)
-    with pytest.raises(ValueError, match="unknown engine policy"):
-        tnn_engine.TNNEngine(
-            params, net,
-            tnn_engine.TNNServeConfig(n_slots=2, policy="fastest"))
-
-
-@pytest.mark.parametrize("pol", ["cost", "density"])
-def test_serve_policy_modes_bit_exact_and_report_stats(pol):
-    net = _small_net()
-    params = network.init_network(jax.random.PRNGKey(0), net)
-    streams = _streams(net, n_req=4)
-    eng = tnn_engine.TNNEngine(
-        params, net,
-        tnn_engine.TNNServeConfig(n_slots=2, policy=pol))
-    results = eng.serve(streams)
-    for stream, result in zip(streams, results):
-        ref = tnn_engine.reference_outputs(params, net, stream)
-        np.testing.assert_array_equal(ref, result)
-    stats = eng.stats()
-    assert stats["policy_mode"] == (1.0 if pol == "cost" else 0.0)
-    if pol == "cost":
-        predicted = {k: v for k, v in stats.items()
-                     if k.startswith("steps_predicted_")}
-        assert predicted and sum(predicted.values()) == stats["n_steps"]
-        assert any(k.startswith("predicted_us_mean_") for k in stats)
-
-
-def test_layer_policy_field_threads_to_bank():
-    """A layer pinned to the density policy evaluates bit-exact against
-    the default cost policy (engine choice never changes outputs)."""
-    l_cost = layer.TNNLayer(n_columns=2, rf_size=4, n_neurons=3,
-                            threshold=5, t_steps=12, dendrite="catwalk",
-                            k=2)
-    l_dens = layer.TNNLayer(n_columns=2, rf_size=4, n_neurons=3,
-                            threshold=5, t_steps=12, dendrite="catwalk",
-                            k=2, policy=policy.density_policy())
-    net_c = network.make_network([l_cost])
-    net_d = network.make_network([l_dens])
-    params = network.init_network(jax.random.PRNGKey(1), net_c)
-    rng = np.random.default_rng(3)
-    t = rng.integers(0, 20, size=(net_c.n_inputs,))
-    volley = np.where(t >= 10, NO_SPIKE, t).astype(np.int32)
-    out_c = network.forward(params, volley, net_c)
-    out_d = network.forward(params, volley, net_d)
-    np.testing.assert_array_equal(np.asarray(out_c.out),
-                                  np.asarray(out_d.out))
+def test_resolve_unknown_workload_stays_dense(monkeypatch):
+    """Off a TPU ``auto`` is the closed form whatever the shape; under a
+    mesh an unknown shape (a 2-D bank) cannot take the Pallas kernels, so
+    ``auto`` stays dense even on a TPU and an explicit Pallas request
+    raises."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert policy.resolve_engine("auto") == "closed_form"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert policy.resolve_engine("auto") == "pallas"     # no mesh
+    fake = types.SimpleNamespace(axis_names=("data", "column"),
+                                 shape={"data": 2, "column": 4})
+    monkeypatch.setattr(compat, "get_abstract_mesh", lambda: fake)
+    assert policy.resolve_engine("auto") == "closed_form"
+    assert policy.resolve_engine("auto", ()) == "closed_form"
+    with pytest.raises(ValueError, match="backend='auto'"):
+        policy.resolve_engine("pallas")
 
 
 # ------------------------------------------------- paper-table artifact

@@ -10,8 +10,6 @@ weight columns per neuron). The contract pinned here:
   feedforward network — recurrence adds nothing until something fires;
 * carry threading composes with the pipelined schedule
   (``microbatches=M``) without changing a spike time;
-* the deprecated ``network_forward*`` wrappers warn
-  :class:`ReproDeprecationWarning` and stay bit-exact;
 * the engine round-trips a stream's carry through the slot pool
   (``final_state`` -> ``initial_state`` continuation).
 """
@@ -21,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import _deprecation
 from repro.core import coding, layer, network
 from repro.serve import TNNEngine, TNNServeConfig, tnn_engine
 
@@ -196,30 +193,6 @@ def test_forward_validates_carry_length():
     with pytest.raises(ValueError, match="carry"):
         network.forward(params, v, net,
                         carry=(jnp.zeros((2, 12), jnp.int32),))
-
-
-# --------------------------------------------------- deprecated wrappers
-def test_deprecated_wrappers_warn_and_match():
-    net = network.make_network(
-        [layer.TNNLayer(n_columns=4, rf_size=4, n_neurons=3, threshold=5,
-                        t_steps=12, dendrite="catwalk", k=2)])
-    params = network.init_network(jax.random.PRNGKey(0), net)
-    v = jnp.asarray(_volley_seq(23, 1, 6, net.n_inputs)[0])
-    ref = network.forward(params, v, net)
-    with pytest.warns(_deprecation.ReproDeprecationWarning):
-        # the deprecation test itself  # repro-lint: allow[deprecated-forward]
-        out, win = network.network_forward(params, v, net)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref.out))
-    with pytest.warns(_deprecation.ReproDeprecationWarning):
-        # the deprecation test itself  # repro-lint: allow[deprecated-forward]
-        out_p, _ = network.network_forward_pipelined(params, v, net, 2)
-    np.testing.assert_array_equal(np.asarray(out_p), np.asarray(ref.out))
-    with pytest.warns(_deprecation.ReproDeprecationWarning):
-        # the deprecation test itself  # repro-lint: allow[deprecated-forward]
-        out_d, _, dens = network.network_forward_with_densities(
-            params, v, net)
-    np.testing.assert_array_equal(np.asarray(out_d), np.asarray(ref.out))
-    assert len(dens) == len(net.layers)
 
 
 # --------------------------------------------------------- serving path

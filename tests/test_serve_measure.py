@@ -1,21 +1,24 @@
 """When ``TNNEngine.step`` measures its batch on the host.
 
-The batch density and the layer-0 active-line count feed only the engine
-policy: ``auto`` ranks engines by them off the TPU Pallas fast path, and an
-explicit sparse engine (``event``, ``pallas_compact``) takes its compaction
-width from the active count. Everywhere else the step skips the measurement,
-serves the same outputs, and keeps its ``tnn.measure`` span.
+The engine resolves its neuron-bank engine once, at construction
+(``policy.resolve_engine``); the step never resolves again. Only an
+explicit sparse engine (``event``, ``pallas_compact``) reads the batch:
+its compaction width is bucketed from the layer-0 active-line count.
+Everywhere else the step skips the measurement, serves the same outputs,
+and keeps its ``tnn.measure`` span.
 
-The expected resolutions here are replayed independently of the engine:
-every stream is admitted at step 0 (no more streams than slots), so step k's
+The expected widths here are replayed independently of the engine: every
+stream is admitted at step 0 (no more streams than slots), so step k's
 batch is row i = stream i's cycle k, or all ``NO_SPIKE`` once it ended.
 """
+
+import types
 
 import jax
 import numpy as np
 import pytest
 
-from repro.core import coding, layer, network
+from repro.core import coding, compaction, layer, network
 from repro.core import policy as engine_policy
 from repro.serve import tnn_engine
 
@@ -54,33 +57,13 @@ def _batches(net, streams):
         yield batch
 
 
-def _replay(pol, backend, net, streams):
-    """(density, Resolution) of every step, measured as the engine did
-    before the measurement was gated."""
+def _replay_widths(net, streams):
+    """The bucketed layer-0 compaction width of every step."""
     lc = net.layers[0]
-    t = lc.t_steps
     rf0 = np.asarray(lc.rf_index())
-    shape = engine_policy.BankShape(
-        pairs=N_SLOTS * lc.n_columns * lc.n_neurons, n_lines=lc.rf_total,
-        t_steps=t)
-    out = []
-    for batch in _batches(net, streams):
-        density = float(np.mean(batch < t))
-        max_active = int((batch[:, rf0] < t).sum(axis=-1).max())
-        out.append((density, pol.resolve(
-            backend, density=density, max_active=max_active,
-            column_counts=net.column_counts, shape=shape)))
-    return out
-
-
-def _serve(net, params, streams, **scfg):
-    eng = tnn_engine.TNNEngine(
-        params, net, tnn_engine.TNNServeConfig(n_slots=N_SLOTS, **scfg))
-    results = eng.serve([s.copy() for s in streams])
-    for stream, result in zip(streams, results):
-        np.testing.assert_array_equal(
-            tnn_engine.reference_outputs(params, net, stream), result)
-    return eng, eng.stats()
+    return [compaction.bucket_width(
+                int((batch[:, rf0] < lc.t_steps).sum(axis=-1).max()))
+            for batch in _batches(net, streams)]
 
 
 def _no_measurement(monkeypatch):
@@ -90,90 +73,76 @@ def _no_measurement(monkeypatch):
     monkeypatch.setattr(tnn_engine.TNNEngine, "_layer0_active", refuse)
 
 
-@pytest.mark.parametrize("backend", ["pallas", "closed_form"])
+def _no_resolution(monkeypatch):
+    """From here on the serve engine's own view of the policy module
+    refuses to resolve (the banks' trace-time pass-through of their
+    pinned engine name stays available)."""
+    def refuse(*args, **kwargs):
+        raise AssertionError("the step resolved the engine again")
+
+    monkeypatch.setattr(tnn_engine, "engine_policy", types.SimpleNamespace(
+        SPARSE_ENGINES=engine_policy.SPARSE_ENGINES, resolve_engine=refuse))
+
+
+def _serve(net, params, streams, monkeypatch, platform=None, **scfg):
+    """Construct the engine (on ``platform`` when given), then serve with
+    resolution refused and check every result against the reference."""
+    with monkeypatch.context() as m:
+        if platform is not None:
+            m.setattr(jax, "default_backend", lambda: platform)
+        eng = tnn_engine.TNNEngine(
+            params, net, tnn_engine.TNNServeConfig(n_slots=N_SLOTS, **scfg))
+    _no_resolution(monkeypatch)
+    results = eng.serve([s.copy() for s in streams])
+    for stream, result in zip(streams, results):
+        np.testing.assert_array_equal(
+            tnn_engine.reference_outputs(params, net, stream), result)
+    return eng, eng.stats()
+
+
+@pytest.mark.parametrize("backend",
+                         ["pallas", "closed_form", "scan", "auto"])
 def test_explicit_dense_backend_skips_the_measurement(backend, monkeypatch):
-    """An explicit non-sparse engine runs as named: the step measures
-    nothing, reports no density, and serves bit-exact."""
+    """A dense engine — named, or ``auto`` (the closed form off a TPU) —
+    is resolved at construction only: the step resolves nothing, measures
+    nothing, and serves bit-exact from the one compiled step."""
     _no_measurement(monkeypatch)
     net = _net()
     params = network.init_network(jax.random.PRNGKey(0), net)
-    eng, st = _serve(net, params, _streams(net), backend=backend)
+    eng, st = _serve(net, params, _streams(net), monkeypatch,
+                     backend=backend)
     assert st["n_steps"] == 3.0
     assert st["steps_measured"] == 0.0
-    assert "density_mean" not in st
-    assert st[f"steps_{backend}"] == st["n_steps"]
-    assert not eng._fwd_alt
+    assert eng.engine == (backend if backend != "auto" else "closed_form")
+    assert all(lc.backend == eng.engine for lc in eng.net.layers)
+    assert st["jit_variants"] == 0.0
 
 
 def test_auto_on_the_pallas_fast_path_skips_the_measurement(monkeypatch):
-    """Where the Pallas kernel preempts ``auto`` (a TPU), the policy reads
-    no measurement, so the step takes none and every step runs pallas."""
+    """On a TPU ``auto`` resolves to the Pallas kernel at construction;
+    the step then takes no measurement and runs pallas every step. (The
+    platform is patched for the construction only: the kernels run in the
+    interpreter.)"""
     _no_measurement(monkeypatch)
-    monkeypatch.setattr(engine_policy.EnginePolicy, "_pallas_preempts",
-                        lambda self, column_counts: True)
     net = _net()
     params = network.init_network(jax.random.PRNGKey(0), net)
-    eng, st = _serve(net, params, _streams(net), backend="auto")
+    eng, st = _serve(net, params, _streams(net), monkeypatch,
+                     platform="tpu", backend="auto")
+    assert eng.engine == "pallas"
+    assert all(lc.backend == "pallas" for lc in eng.net.layers)
     assert st["steps_measured"] == 0.0
-    assert "density_mean" not in st
-    assert st["steps_pallas"] == st["n_steps"] == 3.0
-    assert not any(k.startswith("predicted_us_mean_") for k in st)
-
-
-@pytest.mark.parametrize("policy", ["cost", "density"])
-def test_auto_off_the_fast_path_measures_every_step(policy):
-    """``auto`` on the CPU measures every step and resolves each one from
-    the batch it measured, as before the gate."""
-    net = _net()
-    params = network.init_network(jax.random.PRNGKey(0), net)
-    streams = _streams(net, seed=3)
-    pol = engine_policy.get_policy(policy)
-    want = _replay(pol, "auto", net, streams)
-    eng, st = _serve(net, params, streams, backend="auto", policy=policy)
-    assert st["steps_measured"] == st["n_steps"] == len(want)
-    assert st["density_mean"] == pytest.approx(
-        np.mean([d for d, _ in want]))
-    engines = [res.engine for _, res in want]
-    for engine in set(engines):
-        assert st[f"steps_{engine}"] == engines.count(engine)
-    widths = {(res.engine, res.width, False) for _, res in want
-              if res.width is not None}
-    assert set(eng._fwd_alt) == widths
-    if policy == "density":
-        assert widths, "the sparse streams should resolve to event"
+    assert st["n_steps"] == 3.0 and st["jit_variants"] == 0.0
 
 
 @pytest.mark.parametrize("backend", ["event", "pallas_compact"])
-def test_explicit_sparse_backend_still_measures(backend):
+def test_explicit_sparse_backend_still_measures(backend, monkeypatch):
     """An explicit sparse engine compiles at the width bucketed from each
-    batch's measured active count: the same jit variants as before."""
+    batch's measured active count: one jit variant per distinct bucket."""
     net = _net()
     params = network.init_network(jax.random.PRNGKey(0), net)
     streams = _streams(net, seed=5)
-    want = _replay(engine_policy.default_policy(), backend, net, streams)
-    eng, st = _serve(net, params, streams, backend=backend)
+    want = _replay_widths(net, streams)
+    eng, st = _serve(net, params, streams, monkeypatch, backend=backend)
+    assert eng.engine == backend
     assert st["steps_measured"] == st["n_steps"] == len(want)
-    assert "density_mean" in st
-    assert st[f"steps_{backend}"] == st["n_steps"]
-    assert set(eng._fwd_alt) == {(backend, res.width, False)
-                                 for _, res in want}
-    assert all(res.width is not None for _, res in want)
-
-
-def test_stage_densities_average_over_measured_steps():
-    """Under pipelining the per-stage densities are means over the
-    measured steps, and absent when no step measured."""
-    net = _net()
-    params = network.init_network(jax.random.PRNGKey(0), net)
-    streams = _streams(net, seed=7)
-    _, st = _serve(net, params, streams, backend="event",
-                   pipeline_microbatches=2)
-    batches = list(_batches(net, streams))
-    t = net.layers[0].t_steps
-    for i, (lo, hi) in enumerate([(0, 2), (2, 4)]):
-        assert st[f"density_stage{i}_mean"] == pytest.approx(
-            np.mean([np.mean(b[lo:hi] < t) for b in batches]))
-    _, st = _serve(net, params, streams, backend="closed_form",
-                   pipeline_microbatches=2)
-    assert st["steps_measured"] == 0.0
-    assert not any(k.startswith("density_stage") for k in st)
+    assert set(eng._variants) == {(w, False) for w in want}

@@ -289,19 +289,16 @@ def test_reset_stats_keeps_pending_work():
 
 
 def test_sparse_engine_compiles_compacted_stack():
-    """A sparse resolution must plumb static compaction widths into the
+    """A sparse engine must plumb static compaction widths into the
     jitted stack: layer 0 gets the measured+bucketed batch width, deeper
-    layers the 1-WTA structural bound — and stay bit-exact. Pinned to the
-    density policy: at this toy size (24 pairs) the cost model correctly
-    ranks closed_form ahead of the event engine's fixed overhead, and
-    what is under test is the sparse plumbing, not the ranking."""
+    layers the 1-WTA structural bound — and stay bit-exact."""
     l1 = layer.TNNLayer(n_columns=2, rf_size=4, n_neurons=3, threshold=5,
                         t_steps=12, dendrite="catwalk", k=2)
     l2 = layer.TNNLayer(n_columns=1, rf_size=6, n_neurons=2, threshold=4,
                         t_steps=12, dendrite="pc_compact")
     net = network.make_network([l1, l2])
     params = _params(net)
-    # sparse streams: ~2 active lines out of 8 -> auto resolves to event
+    # sparse streams: ~2 active lines out of 8
     rng = np.random.default_rng(3)
     streams = []
     for _ in range(5):
@@ -312,56 +309,22 @@ def test_sparse_engine_compiles_compacted_stack():
         streams.append(t)
     eng = tnn_engine.TNNEngine(
         params, net, tnn_engine.TNNServeConfig(n_slots=4,
-                                               policy="density"))
+                                               backend="event"))
     results = eng.serve(streams)
     for stream, result in zip(streams, results):
         np.testing.assert_array_equal(
             tnn_engine.reference_outputs(params, net, stream), result)
-    assert eng.stats().get("steps_event", 0) > 0
-    # every sparse compile is keyed (engine, bucket) and carries widths
-    sparse_keys = [k for k in eng._fwd_alt if k[0] == "event"]
-    assert sparse_keys and all(k[1] is not None for k in sparse_keys)
-    widths = network.sparse_widths(net, sparse_keys[0][1])
-    assert widths[0] == sparse_keys[0][1]
+    assert eng.engine == "event"
+    assert eng.stats()["steps_measured"] > 0
+    # every sparse compile is keyed (bucket, learn) and carries widths
+    sparse_keys = list(eng._variants)
+    assert sparse_keys and all(k[0] is not None and not k[1]
+                               for k in sparse_keys)
+    widths = network.sparse_widths(net, sparse_keys[0][0])
+    assert widths[0] == sparse_keys[0][0]
     # l2 reads l1's post-WTA lines: rf=6 over Q=3 blocks -> at most
     # (6-2)//3 + 2 = 3 active lines, capped at the 2 columns that exist
     assert widths[1] == 2
-
-
-def test_jit_variant_cache_is_bounded_lru():
-    """The per-(engine, width) variant cache is an LRU capped at
-    ``max_jit_variants``: over-cap compiles evict the least recently used
-    executable, hits refresh recency, evictions surface in stats(), and
-    a re-requested evicted variant recompiles and still serves bit-exact."""
-    net = _small_net()
-    params = _params(net)
-    eng = tnn_engine.TNNEngine(
-        params, net,
-        tnn_engine.TNNServeConfig(n_slots=2, max_jit_variants=2))
-    # three distinct variants through a cap of 2
-    eng._fwd_for("event", 8)
-    eng._fwd_for("event", 16)
-    eng._fwd_for("scan", None)                 # evicts ("event", 8)
-    st = eng.stats()
-    assert st["jit_variants"] == 2.0
-    assert st["jit_evictions"] == 1.0
-    assert ("event", 8, False) not in eng._fwd_alt
-    # a hit refreshes recency: ("event", 16) survives the next eviction
-    eng._fwd_for("event", 16)
-    eng._fwd_for("event", 32)                  # evicts ("scan", None)
-    assert set(eng._fwd_alt) == {("event", 16, False), ("event", 32, False)}
-    assert eng.stats()["jit_evictions"] == 2.0
-    # the default compiled step is pinned outside the LRU
-    assert eng._fwd_for(eng._default_engine) is eng._fwd
-    # an evicted variant recompiles on demand and stays bit-exact
-    streams = _streams(net, 3, seed=5)
-    for stream, result in zip(streams, eng.serve(streams)):
-        np.testing.assert_array_equal(
-            tnn_engine.reference_outputs(params, net, stream), result)
-    with pytest.raises(ValueError):
-        tnn_engine.TNNEngine(
-            params, net,
-            tnn_engine.TNNServeConfig(n_slots=2, max_jit_variants=0))
 
 
 def test_sparse_widths_structural_bound():
@@ -380,16 +343,21 @@ def test_engine_backend_override_rewrites_layers():
         _params(net), net,
         tnn_engine.TNNServeConfig(n_slots=2, backend="scan"))
     assert all(lc.backend == "scan" for lc in eng.net.layers)
-    # "auto" leaves the network's own per-layer backends alone
+    assert eng.engine == "scan"
+    # "auto" pins the network's auto layers to the engine it resolved to
+    # once (the closed form off a TPU)
     eng2 = tnn_engine.TNNEngine(
         _params(net), net, tnn_engine.TNNServeConfig(n_slots=2))
-    assert eng2.net is net
+    assert all(lc.backend == "auto" for lc in net.layers)
+    assert all(lc.backend == eng2.engine for lc in eng2.net.layers)
+    if jax.default_backend() == "cpu":
+        assert eng2.engine == "closed_form"
 
 
 def test_engine_backend_override_respects_explicit_layers():
     """An engine-level backend pins only backend="auto" layers — explicit
     per-layer choices survive (regression: __init__ used to clobber every
-    layer, contradicting _fwd_for's documented contract)."""
+    layer)."""
     l1 = layer.TNNLayer(n_columns=2, rf_size=4, n_neurons=3, threshold=5,
                         t_steps=12, dendrite="catwalk", k=2, backend="scan")
     l2 = layer.TNNLayer(n_columns=1, rf_size=6, n_neurons=2, threshold=4,

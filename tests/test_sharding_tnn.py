@@ -115,8 +115,7 @@ def test_sharded_layer_step_training_bit_exact():
 
 def test_sharded_engine_serve_bit_exact():
     """TNNEngine.serve with a mesh == unbatched single-device reference;
-    the auto policy keeps re-resolving per step (density measured on the
-    host batch before placement)."""
+    ``auto`` resolves once, inside the engine's mesh scope."""
     print(_run("""
         from repro.serve import tnn_engine
         streams = [v[:3], v[3:6], v[6:], v[1:2]]
@@ -128,7 +127,7 @@ def test_sharded_engine_serve_bit_exact():
                 tnn_engine.reference_outputs(params, net, s), r)
         st = eng.stats()
         assert st['n_retired'] == 4.0
-        assert any(key.startswith('steps_') for key in st)
+        assert eng.engine == 'closed_form'  # CPU here
         print('SHARDED_ENGINE_BIT_EXACT_OK')
     """))
 
@@ -138,29 +137,27 @@ def test_pallas_mesh_capability_model():
     the Pallas engines run exactly when the column stack tiles the
     ``column`` axis (shard_map fast path, kernels/rnl_shard). For 2-D
     banks, unknown shapes, and non-dividing C an explicit Pallas request
-    raises, while ``auto`` degrades to the bit-exact jnp engines — and
-    serve stats() records whichever engine actually ran."""
+    raises, while ``auto`` degrades to the bit-exact closed form — and
+    the serve engine records whichever engine it resolved to."""
     print(_run("""
         cfgn = l1.neuron_config()
         times_rf = jnp.swapaxes(jnp.asarray(v)[:, l1.rf_index()], 0, 1)
         w = jnp.round(params[0]).astype(jnp.int32)
         ref = np.asarray(neuron.fire_times_bank(times_rf, w, cfgn,
                                                 backend='closed_form'))
-        pol = policy.default_policy()
         with compat.set_mesh(mesh):
             assert neuron.mesh_active()
-            # capability through resolve(): C=8 tiles the 4-way column
-            # axis; C=5 and 2-D banks (no column axis) degrade
-            assert pol.resolve('pallas', column_counts=8).engine == 'pallas'
-            assert pol.resolve(
-                'pallas_compact', column_counts=(8, 4)).engine == \\
-                'pallas_compact'
+            # capability through resolve_engine(): C=8 tiles the 4-way
+            # column axis; C=5 and 2-D banks (no column axis) do not
+            assert policy.resolve_engine('pallas', 8) == 'pallas'
+            assert policy.resolve_engine(
+                'pallas_compact', (8, 4)) == 'pallas_compact'
             # unknown / non-dividing shapes: an explicit Pallas request
             # raises instead of switching engines behind the caller's back
             for name, counts in (('pallas', None), ('pallas', 5),
                                  ('pallas_compact', 5)):
                 try:
-                    pol.resolve(name, column_counts=counts)
+                    policy.resolve_engine(name, counts)
                 except ValueError as e:
                     assert "backend='auto'" in str(e), e
                 else:
@@ -171,23 +168,18 @@ def test_pallas_mesh_capability_model():
                                              backend=backend)
                 np.testing.assert_array_equal(np.asarray(got), ref)
             # auto -> pallas needs a TPU backend AND the capability
-            assert pol.resolve('auto', column_counts=8).requested != \\
-                'pallas'  # CPU here
+            assert policy.resolve_engine('auto', 8) == 'closed_form'  # CPU
             jb, jax.default_backend = jax.default_backend, lambda: 'tpu'
             try:
-                assert pol.resolve(
-                    'auto', column_counts=8).engine == 'pallas'
-                # non-dividing C on "TPU": no pallas; the legacy density
-                # mode then picks the event engine at sparse traffic
-                assert policy.density_policy().resolve(
-                    'auto', column_counts=5, density=0.1).engine == 'event'
+                assert policy.resolve_engine('auto', 8) == 'pallas'
+                # non-dividing C on "TPU": no pallas, the closed form
+                assert policy.resolve_engine('auto', 5) == 'closed_form'
             finally:
                 jax.default_backend = jb
         assert not neuron.mesh_active()
-        assert pol.resolve('pallas').engine == 'pallas'
+        assert policy.resolve_engine('pallas') == 'pallas'
         from repro.serve import tnn_engine
-        # dividing columns (8, 4): the requested engine really runs and
-        # stats() records it — no stale degradation row
+        # dividing columns (8, 4): the requested engine really runs
         eng = tnn_engine.TNNEngine(
             params, net,
             tnn_engine.TNNServeConfig(n_slots=2, backend='pallas'),
@@ -195,8 +187,8 @@ def test_pallas_mesh_capability_model():
         for s, r in zip([v[:2]], eng.serve([v[:2]])):
             np.testing.assert_array_equal(
                 tnn_engine.reference_outputs(params, net, s), r)
-        st = eng.stats()
-        assert st['steps_pallas'] > 0 and 'steps_closed_form' not in st, st
+        assert eng.engine == 'pallas'
+        assert all(lc.backend == 'pallas' for lc in eng.net.layers)
         # non-dividing C=5: an explicit pallas engine refuses to start;
         # auto on "TPU" takes the replication fallback and records it
         try:
@@ -211,18 +203,15 @@ def test_pallas_mesh_capability_model():
         jb, jax.default_backend = jax.default_backend, lambda: 'tpu'
         try:
             engo = tnn_engine.TNNEngine(
-                podd, odd,
-                tnn_engine.TNNServeConfig(n_slots=2, policy='density'),
-                mesh=mesh)
+                podd, odd, tnn_engine.TNNServeConfig(n_slots=2), mesh=mesh)
             served = engo.serve([vodd[:2]])
         finally:
             jax.default_backend = jb
         for s, r in zip([vodd[:2]], served):
             np.testing.assert_array_equal(
                 tnn_engine.reference_outputs(podd, odd, s), r)
-        sto = engo.stats()
-        assert 'steps_pallas' not in sto and sto['steps_closed_form'] > 0, \\
-            sto
+        assert engo.engine == 'closed_form', engo.engine
+        assert engo.stats()['n_steps'] > 0
         print('PALLAS_MESH_CAPABILITY_OK')
     """))
 
